@@ -61,8 +61,8 @@ def _scaling_workload(full: bool):
 
 
 def scaling_child(d: int, full: bool) -> dict:
-    """Measure one device count in THIS process (the parent forced the
-    fake-device flag into our env before jax initialized)."""
+    """Measure one device count in THIS process (on the CPU the parent
+    forced the fake-device flag into our env before jax initialized)."""
     trace, caps, plist, n_req = _scaling_workload(full)
 
     def grid():
@@ -71,15 +71,22 @@ def scaling_child(d: int, full: bool) -> dict:
 
     first, warm, wmin = _timed(grid)
     sims = len(plist) * len(caps) * n_req
+    dev = jax.devices()[0]
     return dict(name=f"fabric_d{d}", mode=f"lane axis over {d} device(s)",
                 n_lanes=len(plist) * len(caps), devices=d,
+                platform=dev.platform, device_kind=dev.device_kind,
                 first_call_s=round(first, 3), warm_s=round(warm, 3),
                 warm_min_s=round(wmin, 3), req_per_s=int(sims / warm))
 
 
 def run_scaling(full: bool) -> list[dict]:
-    """Device-scaling rows: one subprocess per count (max(SCALING_COUNTS)
-    fake host devices forced in each child's env)."""
+    """Device-scaling rows, one per count.  On a chip host they run in this
+    process over the real devices (the chip belongs to this process; a
+    count above the host's devices fails with the fabric's message).  On
+    the CPU each count runs in a subprocess with max(SCALING_COUNTS) fake
+    host devices forced in its env."""
+    if jax.default_backend() != "cpu":
+        return [scaling_child(d, full) for d in SCALING_COUNTS]
     rows = []
     for d in SCALING_COUNTS:
         proc = subprocess.run(

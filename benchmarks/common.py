@@ -21,19 +21,26 @@ def forced_device_env(n: int) -> dict:
 
     The multi-device sweep fabric (repro.launch.fabric, DESIGN.md §13) is
     validated on CPU by faking devices, and the flag only works if set
-    before jax initializes — so multi-device measurement always happens in
-    a child process (the ``benchmarks/probe_memory.py`` pattern).  Any
+    before jax initializes — so multi-device measurement on the CPU
+    happens in a child process (the ``benchmarks/probe_memory.py``
+    pattern).  Only a parent that itself runs on the CPU may ask for this:
+    the child inherits the parent's platform, and on a chip host the
+    parent holds the chip, so chip measurements stay in-process.  Any
     pre-existing device-count flag is replaced outright (a stale count
     surfaces much later as a confusing mesh error); other XLA flags are
     kept."""
     import os
     import re
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"fake host devices apply to the CPU platform only, and this "
+            f"process runs on {jax.default_backend()!r}: measure real "
+            f"devices in-process")
     env = dict(os.environ)
     prior = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
                    env.get("XLA_FLAGS", "")).strip()
     flag = f"--xla_force_host_platform_device_count={n}"
     env["XLA_FLAGS"] = f"{prior} {flag}".strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
     return env
 
 

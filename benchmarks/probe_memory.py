@@ -93,6 +93,7 @@ def simstate_child_row(n_keys: int, mode: str, n_requests: int) -> dict:
     import resource
     import time
 
+    import jax
     import numpy as np
 
     from repro.core import PolicyParams, simulate_stream
@@ -111,8 +112,10 @@ def simstate_child_row(n_keys: int, mode: str, n_requests: int) -> dict:
                         chunk_size=16_384, state_mode=mode)
     lat = float(r.total_latency)
     wall = time.perf_counter() - t0
+    dev = jax.devices()[0]
     return dict(
         n_keys=n_keys, mode=mode, n_requests=n_requests,
+        platform=dev.platform, device_kind=dev.device_kind,
         distinct_touched=distinct,
         n_slots=slot_table_size(distinct) if mode == "slots" else "",
         capacity=round(capacity, 1), latency=round(lat, 4),
@@ -136,10 +139,9 @@ def run_simstate_probe(sizes=SIMSTATE_SIZES, n_requests=SIMSTATE_REQUESTS,
             cmd = [sys.executable, "-m", "benchmarks.probe_memory",
                    "--simstate-child", str(n), mode,
                    "--requests", str(n_requests)]
-            env = dict(os.environ)
-            env.setdefault("JAX_PLATFORMS", "cpu")
-            try:
-                proc = subprocess.run(cmd, cwd=REPO_ROOT, env=env,
+            try:    # the child inherits this process's platform; this
+                # process never touches jax, so a chip is free for it
+                proc = subprocess.run(cmd, cwd=REPO_ROOT,
                                       capture_output=True, text=True,
                                       timeout=timeout_s)
             except subprocess.TimeoutExpired:
@@ -210,6 +212,13 @@ def main(argv=None):
             if "xla_force_host_platform_device_count" not in f]
     os.environ["XLA_FLAGS"] = " ".join(kept + [flag])
     import jax
+
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"the HLO forensics probe compiles for {flag.split('=')[1]} fake "
+            f"host devices, which exist on the CPU platform only; this "
+            f"process runs on {jax.default_backend()!r} (run it with "
+            f"JAX_PLATFORMS=cpu)")
 
     from repro.configs import registry
     from repro.launch.cells import input_specs

@@ -6,31 +6,18 @@ perf-trajectory snapshots (``BENCH_stream.json`` / ``BENCH_sweep.json``)
 at the repo root so future PRs can diff req/s, wall-clock, and peak RSS
 without re-reading EXPERIMENTS prose.
 
-XLA's persistent compilation cache is enabled under
-``benchmarks/.jax_cache`` so repeat invocations skip graph compiles — the
-sweep engine's unified graphs (one per figure) make the cache small and
-stable across runs (EXPERIMENTS.md §Perf records cold vs warm-cache)."""
+XLA's persistent compilation cache is enabled
+(:func:`repro.launch.compile_cache.enable_compile_cache`: the directory
+``JAX_COMPILATION_CACHE_DIR`` names, else ``.jax_cache/`` at the repo
+root) so repeat invocations skip graph compiles — the sweep engine's
+unified graphs (one per figure) make the cache small and stable across
+runs (EXPERIMENTS.md §Perf records cold vs warm-cache)."""
 from __future__ import annotations
 
 import argparse
 import sys
 import time
 from pathlib import Path
-
-
-def _enable_compile_cache() -> None:
-    import jax
-    import os
-    try:
-        # honor an externally pinned cache dir (CI's JAX_COMPILATION_CACHE_DIR)
-        # instead of clobbering it; the default lives under benchmarks/ and
-        # is gitignored — compile-cache blobs must never be tracked
-        cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") \
-            or str(Path(__file__).parent / ".jax_cache")
-        jax.config.update("jax_compilation_cache_dir", str(cache))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.05)
-    except Exception:
-        pass    # older jaxlibs: benchmarks still run, just recompile
 
 
 def _run_memory_probe() -> None:
@@ -62,7 +49,8 @@ def main() -> int:
                     help="disable the persistent XLA compilation cache")
     args = ap.parse_args()
     if not args.no_compile_cache:
-        _enable_compile_cache()
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     want = set(args.only.split(",")) if args.only else None
 
     from . import (bench_kernels, bench_serving, bench_sweep, fig2_synthetic,
@@ -71,6 +59,14 @@ def main() -> int:
     from .common import emit
 
     jobs = [
+        # memory probes (probe_memory.py): SimState RSS scaling rows
+        # (slots vs dense) + model-stack HLO forensics, both as
+        # subprocesses (see _run_memory_probe).  First in line: on a chip
+        # host the children need the chip, which this process holds from
+        # its first jax computation on.  Opt-in only (--only memory): the
+        # cells compile and the dense million-object replay is out of the
+        # cache-benchmark jobs' wall-clock budget.
+        ("memory", _run_memory_probe),
         ("fig3", lambda: emit(fig3_trace_stats.run(), "fig3_trace_stats")),
         ("fig2", lambda: emit(fig2_synthetic.run(full=args.full),
                               "fig2_synthetic")),
@@ -90,12 +86,6 @@ def main() -> int:
         # closed-loop serving tails: appends BENCH_serving.json history
         ("serving", lambda: emit(bench_serving.run(full=args.full),
                                  "bench_serving")),
-        # memory probes (probe_memory.py): SimState RSS scaling rows
-        # (slots vs dense) + model-stack HLO forensics, both as
-        # subprocesses (see _run_memory_probe).  Opt-in only
-        # (--only memory): the cells compile and the dense million-object
-        # replay is out of the cache-benchmark jobs' wall-clock budget.
-        ("memory", _run_memory_probe),
     ]
     for name, fn in jobs:
         if want is None and name == "memory":

@@ -16,9 +16,9 @@ The per-commit scoring hot path is one shared-substrate pass
 cheap epilogue, fused with a masked top-E victim-order select that the
 evict-until-fit loop consumes in O(1) per victim (DESIGN.md §10); it can
 run through the fused Pallas kernel (:mod:`repro.kernels.ranking_score`)
-via ``use_kernel`` — compiled on TPU, interpret-mode or the jnp reference
-on CPU (DESIGN.md §3).  The unjitted :func:`_simulate_impl` is the
-composition point for :mod:`repro.core.sweep`, which vmaps it over whole
+via ``use_kernel`` — compiled on TPU; interpret-mode or the jnp reference
+on CPU, named explicitly (DESIGN.md §3).  The unjitted
+:func:`_simulate_impl` is the composition point for :mod:`repro.core.sweep`, which vmaps it over whole
 hyperparameter grids.
 
 The commit/evict/serve core is deliberately exposed as free functions over
@@ -64,7 +64,7 @@ def _tree_sel(flag, new, old):
 #   'rank'             — the policy's jnp rank function (default)
 #   'kernel'           — fused Pallas kernel, compiled (TPU)
 #   'kernel_interpret' — fused Pallas kernel, interpret mode (any backend)
-#   'ref'              — kernels.ref jnp oracle (CPU fallback, same math)
+#   'ref'              — kernels.ref jnp oracle (same math, any backend)
 _SCORE_MODES = ("rank", "kernel", "kernel_interpret", "ref")
 
 # State-update lowerings (_Behavior.update; DESIGN.md §11): 'scatter' for
@@ -1050,12 +1050,19 @@ _simulate = jax.jit(_simulate_impl,
 def resolve_score_mode(use_kernel) -> str:
     """Map the user-facing ``use_kernel`` flag to a static scoring backend.
 
-    False -> 'rank'; True -> compiled kernel on TPU, jnp ref oracle on CPU;
+    False -> 'rank'; True -> the compiled kernel, which needs a TPU (off
+    one it raises rather than quietly scoring elsewhere);
     'interpret'/'ref'/'kernel' force a specific backend."""
     if use_kernel is False or use_kernel is None:
         return "rank"
     if use_kernel is True:
-        return "kernel" if jax.default_backend() == "tpu" else "ref"
+        if jax.default_backend() != "tpu":
+            raise ValueError(
+                f"use_kernel=True compiles the Pallas scoring kernel for a "
+                f"TPU, but the default backend is "
+                f"{jax.default_backend()!r}; pass use_kernel='interpret' "
+                f"(Pallas interpreter) or 'ref' (jnp oracle) off-TPU")
+        return "kernel"
     if use_kernel == "interpret":
         return "kernel_interpret"
     if use_kernel in _SCORE_MODES:

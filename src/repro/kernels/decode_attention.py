@@ -71,7 +71,7 @@ def _dec_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     static_argnames=("window", "softcap", "sink", "block_k", "interpret"))
 def decode_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
                      softcap: float = 0.0, sink: int = 0,
-                     block_k: int = 512, interpret: bool = True):
+                     block_k: int = 512, interpret: bool = False):
     """q (B,1,H,dh); k,v (B,Sk,KV,dh); q_pos (1,), k_pos (Sk,).
     Returns (B,1,H,dh)."""
     b, sq, h, dh = q.shape

@@ -78,7 +78,7 @@ def _fa_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
                      "interpret"))
 def flash_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
                     softcap: float = 0.0, sink: int = 0, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool = False):
     """q (B,Sq,H,dh); k,v (B,Sk,KV,dh); q_pos (Sq,), k_pos (Sk,) absolute
     positions. Returns (B,Sq,H,dh)."""
     b, sq, h, dh = q.shape

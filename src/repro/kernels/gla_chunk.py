@@ -79,7 +79,7 @@ def _gla_kernel(q_ref, k_ref, v_ref, b_ref, li_ref, y_ref, sT_ref, nT_ref,
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "normalize", "interpret"))
 def gla_chunk(q, k, v, log_f, log_i, *, chunk: int = 256,
-              normalize: bool = True, interpret: bool = True):
+              normalize: bool = True, interpret: bool = False):
     """q,k (B,S,H,dk); v (B,S,H,dv); gates (B,S,H).
     Returns (y (B,S,H,dv), (S_state (B,H,dk,dv), n (B,H,dk)))."""
     b, s, h, dk = q.shape

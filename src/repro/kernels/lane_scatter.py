@@ -12,12 +12,16 @@ discipline here is the MoE dispatch one (in-group scatter with
 lane-varying targets, GShard-style): touch exactly the ``L`` addressed
 elements, never the ``L*N`` table.
 
-This module is the TPU lowering of that discipline: grid over lanes, each
-program copies its row block through VMEM once and patches the addressed
-element with a ``pl.ds`` dynamic store — O(row) VMEM traffic, no [L, N]
-select materialization, and the index arithmetic stays in SMEM.  The jnp
-reference (:func:`repro.kernels.ref.lane_scatter_set_ref` /
-``lane_scatter_add_ref`` — one gather/scatter over the lane diagonal) is
+This module is the TPU lowering of that discipline: grid over lanes, the
+lane indices ride in scalar prefetch (SMEM), and each program's block is
+the one 128-lane window of its row that holds the addressed element — the
+output aliases the input, so every other window keeps its bits without
+being copied.  Inside the window the element is patched with a masked
+select (no dynamic-offset store).  The ``[L, N]`` table is viewed as
+``[L, 1, N]`` so a ``(1, 1, 128)`` block obeys the TPU's 8x128 tiling rule
+for any ``L`` and ``N``; a partial last window is padded on read and masked
+on write.  The jnp reference (:func:`repro.kernels.ref.lane_scatter_set_ref`
+/ ``lane_scatter_add_ref`` — one gather/scatter over the lane diagonal) is
 the CPU fast path and the allclose/bitwise ground truth; interpret mode
 runs the kernel itself on any backend (tests/test_kernels.py pins all
 three against the one-hot oracle across lane counts and dtypes).
@@ -34,69 +38,60 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_WINDOW = 128
 
 
-def _scatter_kernel(x_ref, idx_ref, val_ref, out_ref, *, add: bool):
-    """One grid step = one lane: copy the row, patch element ``idx``."""
-    row = x_ref[0, :]
-    out_ref[0, :] = row
-    i = idx_ref[0]
-    v = val_ref[pl.ds(0, 1)]
-    if add:
-        v = out_ref[0, pl.ds(i, 1)] + v
-    out_ref[0, pl.ds(i, 1)] = v
-
-
-def _resolve_interpret(interpret) -> bool:
-    """``None`` (the default) compiles on TPU and interprets elsewhere —
-    the same correct-by-default backend rule as ``use_kernel=True``
-    scoring (DESIGN.md §3); pass an explicit bool to force a mode."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
+def _scatter_kernel(idx_ref, val_ref, x_ref, out_ref, *, add: bool):
+    """One grid step = one lane: patch element ``idx`` of its window."""
+    lane = pl.program_id(0)
+    i = idx_ref[lane]
+    v = val_ref[lane]
+    row = x_ref[...]
+    col = (jax.lax.broadcasted_iota(jnp.int32, row.shape, 2)
+           + (i // _WINDOW) * _WINDOW)
+    out_ref[...] = jnp.where(col == i, row + v if add else v, row)
 
 
 def _lane_scatter(x, idx, val, *, add: bool, interpret: bool):
     lanes, n = x.shape
-    dtype = x.dtype
-    as_i32 = dtype == jnp.bool_
+    as_i32 = x.dtype == jnp.bool_
     if as_i32:
         x, val = x.astype(jnp.int32), val.astype(jnp.int32)
+    window = pl.BlockSpec((1, 1, _WINDOW),
+                          lambda l, idx, val: (l, 0, idx[l] // _WINDOW))
     out = pl.pallas_call(
         functools.partial(_scatter_kernel, add=add),
-        grid=(lanes,),
-        in_specs=[
-            pl.BlockSpec((1, n), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((lanes, n), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(lanes,),
+            in_specs=[window], out_specs=window),
+        out_shape=jax.ShapeDtypeStruct((lanes, 1, n), x.dtype),
+        input_output_aliases={2: 0},
         interpret=interpret,
-    )(x, idx.astype(jnp.int32), val)
+    )(idx.astype(jnp.int32), val, x.reshape(lanes, 1, n)).reshape(lanes, n)
     return out.astype(jnp.bool_) if as_i32 else out
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def lane_scatter_set(x, idx, val, *, interpret: bool | None = None):
+def lane_scatter_set(x, idx, val, *, interpret: bool = False):
     """``x[l, idx[l]] = val[l]`` per lane; x ``[L, N]``, idx/val ``[L]``.
 
-    ``interpret=None`` resolves by backend (compiled on TPU, Pallas
-    interpreter elsewhere — :func:`_resolve_interpret`).  Bitwise
-    identical to the one-hot lowering
-    ``vmap(lambda r, j, v: where(arange(N) == j, v, r))`` and to the jnp
-    reference — untouched positions are copied, the addressed position
-    takes ``val`` exactly."""
+    ``interpret=True`` runs the Pallas interpreter (any backend); the
+    default compiles for the TPU.  Bitwise identical to the one-hot
+    lowering ``vmap(lambda r, j, v: where(arange(N) == j, v, r))`` and to
+    the jnp reference — untouched positions are kept, the addressed
+    position takes ``val`` exactly."""
     return _lane_scatter(x, idx, jnp.asarray(val, x.dtype), add=False,
-                         interpret=_resolve_interpret(interpret))
+                         interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def lane_scatter_add(x, idx, val, *, interpret: bool | None = None):
+def lane_scatter_add(x, idx, val, *, interpret: bool = False):
     """``x[l, idx[l]] += val[l]`` per lane (logical-or for bool ``x``).
 
-    ``interpret`` resolves as in :func:`lane_scatter_set`.  The sum is
-    computed on the gathered element — bit-identical to the one-hot
-    lowering's ``where(hot, x + v, x)`` at the addressed position."""
+    ``interpret`` as in :func:`lane_scatter_set`.  The sum is computed on
+    the addressed element — bit-identical to the one-hot lowering's
+    ``where(hot, x + v, x)`` at that position."""
     return _lane_scatter(x, idx, jnp.asarray(val, x.dtype), add=True,
-                         interpret=_resolve_interpret(interpret))
+                         interpret=interpret)

@@ -19,7 +19,7 @@ __all__ = ["flash_attention", "decode_attention", "gla_chunk",
 
 
 def gla_chunk_kernel_apply(q, k, v, log_f, log_i, *, chunk: int = 256,
-                           normalize: bool = True, interpret: bool = True):
+                           normalize: bool = True, interpret: bool = False):
     """Adapter with the models/ssm.py chunked_gla return convention."""
     return gla_chunk(q, k, v, log_f, log_i, chunk=chunk,
                      normalize=normalize, interpret=interpret)

@@ -1,15 +1,22 @@
 """Fused eviction-ranking kernel (Pallas, TPU target) — the paper's hot loop.
 
 Computes eq. 16 scores for the whole object table and the block-local
-argmin victim in ONE streaming pass: score = (E[D] + w*sigma[D]) / (R * s)
-with Theorem-2 moments, non-cached entries masked to +inf.  The table is
-memory-bound (five f32 streams, ~10 flops/element) so fusing score+mask+
-argmin keeps it at one HBM read instead of the ~7 kernel launches the
-unfused jnp version costs.  Block-local (min, argmin) pairs stream out; the
-final O(N/block) reduction is a trivial XLA argmin.
+ascending victim candidates in ONE streaming pass: score =
+(E[D] + w*sigma[D]) / (R * s) with Theorem-2 moments, non-cached entries
+masked to the finite sentinel ``INF``.  The table is memory-bound (five f32
+streams, ~10 flops/element) so fusing score+mask+select keeps it at one HBM
+read instead of the ~7 kernel launches the unfused jnp version costs.
 
-Grid: (N / block,); block is lane-aligned (multiple of 128; stats are 1-D so
-tiles are (8, 128)-friendly after the internal reshape).
+TPU layout: the 1-D ``(N,)`` streams are padded and viewed as ``(rows,
+128)`` lane tiles; a block is ``block // 128`` rows (a multiple of 8 rows,
+i.e. ``block`` a multiple of 1024, unless one block covers the whole
+table).  ``omega`` rides in as a broadcast ``(1, 128)`` tile, and each
+block's candidates are written into one lane-dense ``(8k, 128)`` tile
+(candidate ``e`` at flat position ``e``) — every block shape the compiler
+sees obeys the 8x128 rule, and no value is read or stored at a dynamic
+index: the in-kernel argmin is a min-reduction followed by a min over the
+flat indices that attain it, which is ``argmin``'s first-minimum
+convention.  Block-local candidates are merged by a tiny XLA pass.
 """
 from __future__ import annotations
 
@@ -20,12 +27,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 INF = 3.4e38  # python float: jnp constants would be captured by the kernel
+_LANES = 128
+_SUBLANES = 8
 
 
-def _rank_kernel(om_ref, lam_ref, z_ref, r_ref, s_ref, c_ref, f_ref, bmin_ref,
-                 barg_ref, *, block: int):
+def _rank_select_kernel(om_ref, lam_ref, z_ref, r_ref, s_ref, c_ref, f_ref,
+                        bvals_ref, bidx_ref, *, top: int):
+    """Eq.-16 scores + block-local top-``top`` ascending victim candidates,
+    one VMEM-resident pass.  The top-E extraction is ``top`` unrolled
+    masked-min rounds over the block (top is small and static), so the
+    five input streams are still read exactly once per element."""
     ib = pl.program_id(0)
-    omega = om_ref[0]
+    rows, lanes = lam_ref.shape
+    omega = om_ref[...]
     lam = lam_ref[...]
     z = z_ref[...]
     z2 = z * z
@@ -35,86 +49,86 @@ def _rank_kernel(om_ref, lam_ref, z_ref, r_ref, s_ref, c_ref, f_ref, bmin_ref,
         jnp.maximum(r_ref[...], 1e-6) * jnp.maximum(s_ref[...], 1e-6))
     f_ref[...] = f
     masked = jnp.where(c_ref[...] != 0, f, INF)
-    idx = jnp.argmin(masked)
-    bmin_ref[0] = masked[idx]
-    barg_ref[0] = idx.astype(jnp.int32) + ib * block
+    flat = (jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0) * lanes
+            + jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1))
+    crow, ccol = bvals_ref.shape
+    cpos = (jax.lax.broadcasted_iota(jnp.int32, (crow, ccol), 0) * ccol
+            + jax.lax.broadcasted_iota(jnp.int32, (crow, ccol), 1))
+    vals = jnp.full((crow, ccol), INF, jnp.float32)
+    idxs = jnp.zeros((crow, ccol), jnp.int32)
+    base = ib * (rows * lanes)
+    for e_i in range(top):
+        mn = jnp.min(masked)
+        idx = jnp.min(jnp.where(masked == mn, flat, rows * lanes))
+        vals = jnp.where(cpos == e_i, mn, vals)
+        idxs = jnp.where(cpos == e_i, idx + base, idxs)
+        masked = jnp.where(flat == idx, INF, masked)
+    bvals_ref[...] = vals
+    bidx_ref[...] = idxs
+
+
+def _rank_select(lam, z, resid, sizes, cached, omega, top: int, block: int,
+                 interpret: bool):
+    """Run the fused pass; returns ``(scores (N,), cand_vals [G, top],
+    cand_idx [G, top])`` with each block's candidates in extraction order."""
+    n = lam.shape[0]
+    if block % _LANES:
+        raise ValueError(f"block={block} must be a multiple of {_LANES}")
+    rows = -(-n // _LANES)
+    brows = min(block // _LANES, rows)
+    if top > brows * _LANES:
+        # a single block could then hold more of the global top than it can
+        # emit, breaking the union-containment argument of the merge
+        raise ValueError(f"top={top} must be <= block={brows * _LANES}")
+    npad = -(-rows // brows) * brows * _LANES
+    grid = (npad // (brows * _LANES),)
+
+    def tiles(x, fill):
+        x = jnp.pad(x, (0, npad - n), constant_values=fill)
+        return x.reshape(npad // _LANES, _LANES)
+
+    crows = _SUBLANES * -(-top // (_SUBLANES * _LANES))
+    om = jnp.broadcast_to(jnp.asarray(omega, jnp.float32), (1, _LANES))
+    stream = pl.BlockSpec((brows, _LANES), lambda i: (i, 0))
+    cand = pl.BlockSpec((crows, _LANES), lambda i: (i, 0))
+    f, bvals, bidx = pl.pallas_call(
+        functools.partial(_rank_select_kernel, top=top),
+        grid=grid,
+        in_specs=[pl.BlockSpec((1, _LANES), lambda i: (0, 0))] + [stream] * 5,
+        out_specs=[stream, cand, cand],
+        out_shape=[
+            jax.ShapeDtypeStruct((npad // _LANES, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0] * crows, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0] * crows, _LANES), jnp.int32),
+        ],
+        interpret=interpret,
+    )(om, tiles(lam.astype(jnp.float32), 0), tiles(z.astype(jnp.float32), 0),
+      tiles(resid.astype(jnp.float32), 1), tiles(sizes.astype(jnp.float32), 1),
+      tiles(cached.astype(jnp.int32), 0))
+    per_block = lambda c: c.reshape(grid[0], crows * _LANES)[:, :top]
+    return f.reshape(-1)[:n], per_block(bvals), per_block(bidx)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def ranking_scores(lam, z, resid, sizes, cached, *, omega=1.0,
-                   block: int = 1024, interpret: bool = True):
+                   block: int = 1024, interpret: bool = False):
     """All inputs (N,); returns (scores (N,), victim_idx, victim_score).
 
     ``omega`` is a scalar *operand* (python float or traced f32) so the
     simulator can thread a swept PolicyParams.omega through without
-    retracing — it rides in as a broadcast (1,)-block input.
+    retracing.  ``interpret=True`` runs the Pallas interpreter (any
+    backend); the default compiles for the TPU.
     """
-    n = lam.shape[0]
-    block = min(block, max(128, n))
-    pad = (-n) % block
-    if pad:
-        ext = lambda x, v: jnp.pad(x, (0, pad), constant_values=v)
-        lam, z = ext(lam, 0), ext(z, 0)
-        resid, sizes = ext(resid, 1), ext(sizes, 1)
-        cached = ext(cached.astype(jnp.int32), 0)
-    else:
-        cached = cached.astype(jnp.int32)
-    npad = n + pad
-    grid = (npad // block,)
-    om = jnp.asarray(omega, jnp.float32).reshape(1)
-
-    f, bmin, barg = pl.pallas_call(
-        functools.partial(_rank_kernel, block=block),
-        grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda i: (0,))] +
-                 [pl.BlockSpec((block,), lambda i: (i,))] * 5,
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((npad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.int32),
-        ],
-        interpret=interpret,
-    )(om, lam.astype(jnp.float32), z.astype(jnp.float32),
-      resid.astype(jnp.float32), sizes.astype(jnp.float32), cached)
-
-    ib = jnp.argmin(bmin)
-    return f[:n], barg[ib], bmin[ib]
-
-
-def _rank_select_kernel(om_ref, lam_ref, z_ref, r_ref, s_ref, c_ref, f_ref,
-                        bvals_ref, bidx_ref, *, block: int, top: int):
-    """Eq.-16 scores + block-local top-``top`` ascending victim candidates,
-    one VMEM-resident pass.  The top-E extraction is ``top`` unrolled
-    masked-argmin rounds over the block (top is small and static), so the
-    five input streams are still read exactly once per element."""
-    ib = pl.program_id(0)
-    omega = om_ref[0]
-    lam = lam_ref[...]
-    z = z_ref[...]
-    z2 = z * z
-    e = z + lam * z2
-    var = z2 + 6.0 * lam * z2 * z + 5.0 * lam * lam * z2 * z2
-    f = (e + omega * jnp.sqrt(var)) / (
-        jnp.maximum(r_ref[...], 1e-6) * jnp.maximum(s_ref[...], 1e-6))
-    f_ref[...] = f
-    masked = jnp.where(c_ref[...] != 0, f, INF)
-    lanes = jnp.arange(block)
-    for e_i in range(top):
-        idx = jnp.argmin(masked)
-        bvals_ref[0, e_i] = masked[idx]
-        bidx_ref[0, e_i] = idx.astype(jnp.int32) + ib * block
-        masked = jnp.where(lanes == idx, INF, masked)
+    f, bvals, bidx = _rank_select(lam, z, resid, sizes, cached, omega, 1,
+                                  block, interpret)
+    ib = jnp.argmin(bvals[:, 0])
+    return f, bidx[ib, 0], bvals[ib, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("top", "block", "interpret"))
 def ranking_victim_order(lam, z, resid, sizes, cached, *, omega=1.0,
                          top: int = 8, block: int = 1024,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """Fused rank-and-select: eq. 16 scores AND the masked top-``top``
     ascending victim order in one streaming pass (DESIGN.md §10).
 
@@ -123,64 +137,29 @@ def ranking_victim_order(lam, z, resid, sizes, cached, *, omega=1.0,
     ascending ``(score, index)`` order — the same sequence as
     :func:`repro.kernels.ref.victim_order_ref`.  Block-local candidates are
     extracted in-kernel (one HBM read for score + mask + select, vs the
-    score-then-sort round trip of the unfused path) and merged host-side
-    with a tiny ``top_k`` over ``grid * top`` survivors; candidate values
-    at or above the finite in-kernel ``INF`` sentinel are converted to
-    exact ``+inf`` (scores above 3.4e38 are treated as +inf, the kernel
-    family's pre-existing convention).  A block with fewer cached entries
-    than ``top`` keeps emitting sentinel-valued candidates (whose lane
-    index is meaningless), so the +inf conversion must key on the
-    *candidate value*, never re-derive it from the index — an index-based
-    re-mask would resurrect finite scores for already-emitted victims and
-    break the consumer's evict-until-fit accounting.  The global
-    top-``top`` is always contained in the union of block-local
-    top-``top``s, and both levels break ties toward lower indices, so the
-    merged order matches the jnp oracle wherever values are finite (+inf
-    tail positions may carry different — meaningless — indices).
+    score-then-sort round trip of the unfused path) and merged with a tiny
+    ``top_k`` over ``grid * top`` survivors; candidate values at or above
+    the finite in-kernel ``INF`` sentinel are converted to exact ``+inf``
+    (scores above 3.4e38 are treated as +inf, the kernel family's
+    pre-existing convention).  A block with fewer cached entries than
+    ``top`` keeps emitting sentinel-valued candidates (whose lane index is
+    meaningless), so the +inf conversion must key on the *candidate
+    value*, never re-derive it from the index — an index-based re-mask
+    would resurrect finite scores for already-emitted victims and break
+    the consumer's evict-until-fit accounting.  The global top-``top`` is
+    always contained in the union of block-local top-``top``s, and both
+    levels break ties toward lower indices, so the merged order matches
+    the jnp oracle wherever values are finite (+inf tail positions may
+    carry different — meaningless — indices).  ``interpret`` as in
+    :func:`ranking_scores`.
     """
-    n = lam.shape[0]
-    top = max(1, min(top, n))
-    block = min(block, max(128, n))
-    if top > block:
-        # a single block could then hold more of the global top than it can
-        # emit, breaking the union-containment argument above
-        raise ValueError(f"top={top} must be <= block={block}")
-    pad = (-n) % block
-    if pad:
-        ext = lambda x, v: jnp.pad(x, (0, pad), constant_values=v)
-        lam, z = ext(lam, 0), ext(z, 0)
-        resid, sizes = ext(resid, 1), ext(sizes, 1)
-        cached = ext(cached.astype(jnp.int32), 0)
-    else:
-        cached = cached.astype(jnp.int32)
-    npad = n + pad
-    grid = (npad // block,)
-    ktop = min(top, block)
-    om = jnp.asarray(omega, jnp.float32).reshape(1)
-
-    f, bvals, bidx = pl.pallas_call(
-        functools.partial(_rank_select_kernel, block=block, top=ktop),
-        grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda i: (0,))] +
-                 [pl.BlockSpec((block,), lambda i: (i,))] * 5,
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1, ktop), lambda i: (i, 0)),
-            pl.BlockSpec((1, ktop), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((npad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], ktop), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], ktop), jnp.int32),
-        ],
-        interpret=interpret,
-    )(om, lam.astype(jnp.float32), z.astype(jnp.float32),
-      resid.astype(jnp.float32), sizes.astype(jnp.float32), cached)
-
+    top = max(1, min(top, lam.shape[0]))
+    f, bvals, bidx = _rank_select(lam, z, resid, sizes, cached, omega, top,
+                                  block, interpret)
     # merge: candidate arrays are ordered (block, extraction rank), which for
     # equal values coincides with global index order — top_k's positional
     # tie-break therefore reproduces the argmin convention across blocks.
     neg, pos = jax.lax.top_k(-bvals.reshape(-1), top)
     idx = bidx.reshape(-1)[pos]
     vals = jnp.where(-neg >= INF, jnp.inf, -neg)
-    return f[:n], idx, vals
+    return f, idx, vals

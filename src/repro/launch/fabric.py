@@ -34,11 +34,6 @@ import functools
 import jax
 from jax.sharding import PartitionSpec
 
-try:                # moved out of experimental in newer jax
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 __all__ = ["resolve_fabric", "fabric_lane_multiple", "fabric_sweep_single",
            "fabric_sweep_multi", "fabric_hier_single", "fabric_hier_multi"]
 
@@ -97,14 +92,13 @@ def _specs(mesh):
 
 def _mk_shard_map(body, mesh):
     in_specs, out_specs = _specs(mesh)
-    try:                # per-lane scans never communicate, and outputs are
-        # genuinely lane-sharded — replication checking has nothing to
-        # verify here and lacks a while_loop rule on older jax
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:   # newer jax dropped/renamed check_rep
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    # per-lane scans never communicate and outputs are genuinely
+    # lane-sharded, so varying-manual-axes checking has nothing to verify —
+    # and with it on, the commit lax.cond's branches (one touches the
+    # device-varying lane state, one passes constants through) fail its
+    # equal-output-type rule
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # One compiled callable per (mesh, entry point, static config): the cache

@@ -7,16 +7,10 @@ device query.
 from __future__ import annotations
 
 import jax
-
-try:                # jax >= 0.5 names explicit/auto axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jaxlibs: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -57,7 +51,5 @@ def make_data_mesh(n_devices: int | None = None, devices=None):
                 f"jax initializes (the subprocess pattern of "
                 f"benchmarks/probe_memory.py)")
         devs = devs[:n_devices]
-    if AxisType is None:
-        return jax.sharding.Mesh(np.array(devs), ("data",))
     return jax.sharding.Mesh(np.array(devs), ("data",),
                              axis_types=(AxisType.Auto,))

@@ -88,7 +88,7 @@ def _sdpa_chunked(q, k, v, q_pos, k_pos, *, window: int, softcap: float,
 
 def sdpa(q, k, v, q_pos, k_pos, *, window: int = 0, softcap: float = 0.0,
          sink: int = 0, use_kernel: bool = False,
-         interpret: bool = True, unroll: bool = False) -> jax.Array:
+         interpret: bool = False, unroll: bool = False) -> jax.Array:
     """q: (B,Sq,H,dh); k,v: (B,Sk,KV,dh). Returns (B,Sq,H,dh)."""
     b, sq, h, dh = q.shape
     kv = k.shape[2]

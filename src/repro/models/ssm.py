@@ -25,7 +25,7 @@ from .layers import init_dense
 # ---------------------------------------------------------------------------
 def chunked_gla(q, k, v, log_f, log_i, *, chunk: int = 256,
                 normalize: bool = True, init_state=None, unroll: bool = False,
-                use_kernel: bool = False, interpret: bool = True):
+                use_kernel: bool = False, interpret: bool = False):
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     chunk = min(chunk, s)

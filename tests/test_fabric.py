@@ -116,9 +116,10 @@ print("PARITY " + json.dumps(checks))
 
 
 def _run_child(mode):
+    # fake host devices exist on the CPU platform only; the child inherits
+    # the test run's platform (the CPU route: JAX_PLATFORMS=cpu)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD, SRC, mode],
         capture_output=True, text=True, timeout=570, env=env)

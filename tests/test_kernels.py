@@ -29,7 +29,8 @@ def test_flash_attention_matches_ref(b, sq, sk, h, kv, dh, dtype):
     # q occupies the tail of the k timeline (prefill continuation layout)
     q_pos = jnp.arange(sk - sq, sk, dtype=jnp.int32)
     k_pos = jnp.arange(sk, dtype=jnp.int32)
-    got = flash_attention(q, k, v, q_pos, k_pos, block_q=64, block_k=64)
+    got = flash_attention(q, k, v, q_pos, k_pos, block_q=64, block_k=64,
+                          interpret=True)
     want = ref.flash_attention_ref(q, k, v, q_pos, k_pos)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -45,7 +46,8 @@ def test_flash_attention_masks_and_softcap(window, softcap, sink):
     v = jax.random.normal(ks[2], (b, s, 2, dh), jnp.float32)
     pos = jnp.arange(s, dtype=jnp.int32)
     got = flash_attention(q, k, v, pos, pos, window=window, softcap=softcap,
-                          sink=sink, block_q=64, block_k=64)
+                          sink=sink, block_q=64, block_k=64,
+                          interpret=True)
     want = ref.flash_attention_ref(q, k, v, pos, pos, window=window,
                                    softcap=softcap, sink=sink)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -62,7 +64,8 @@ def test_decode_attention_matches_ref(b, sk, h, kv, dh, dtype):
     v = jax.random.normal(ks[2], (b, sk, kv, dh), dtype)
     q_pos = jnp.array([sk - 1], jnp.int32)
     k_pos = jnp.arange(sk, dtype=jnp.int32)
-    got = decode_attention(q, k, v, q_pos, k_pos, block_k=128)
+    got = decode_attention(q, k, v, q_pos, k_pos, block_k=128,
+                           interpret=True)
     want = ref.decode_attention_ref(q, k, v, q_pos, k_pos)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -77,7 +80,8 @@ def test_decode_attention_ring_buffer_masking():
     v = jax.random.normal(ks[2], (b, sk, kv, dh), jnp.float32)
     k_pos = jnp.where(jnp.arange(sk) < 70, jnp.arange(sk), -1).astype(jnp.int32)
     q_pos = jnp.array([69], jnp.int32)
-    got = decode_attention(q, k, v, q_pos, k_pos, block_k=64)
+    got = decode_attention(q, k, v, q_pos, k_pos, block_k=64,
+                           interpret=True)
     want = ref.decode_attention_ref(q, k, v, q_pos, k_pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -97,7 +101,7 @@ def test_gla_chunk_matches_sequential_ref(b, s, h, dk, dv, chunk, normalize):
     log_f = -jax.nn.softplus(-jax.random.normal(ks[3], (b, s, h)) - 1.0)
     log_i = -jax.nn.softplus(-jax.random.normal(ks[4], (b, s, h)))
     y, (S, n) = gla_chunk(q, k, v, log_f, log_i, chunk=chunk,
-                          normalize=normalize)
+                          normalize=normalize, interpret=True)
     y_ref, (S_ref, n_ref) = ref.gla_chunk_ref(q, k, v, log_f, log_i,
                                               normalize=normalize)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
@@ -118,7 +122,8 @@ def test_gla_chunk_equals_model_chunked_gla():
     v = jax.random.normal(ks[2], (b, s, h, dv), jnp.float32)
     log_f = -jax.nn.softplus(-jax.random.normal(ks[3], (b, s, h)))
     log_i = -jax.nn.softplus(-jax.random.normal(ks[4], (b, s, h)))
-    y_k, (s_k, n_k) = gla_chunk(q, k, v, log_f, log_i, chunk=32)
+    y_k, (s_k, n_k) = gla_chunk(q, k, v, log_f, log_i, chunk=32,
+                                interpret=True)
     y_x, (s_x, n_x) = chunked_gla(q, k, v, log_f, log_i, chunk=32)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_x),
                                atol=1e-4, rtol=1e-3)
@@ -136,7 +141,7 @@ def test_ranking_scores_matches_ref(n, omega):
     sizes = jax.random.uniform(ks[3], (n,), minval=1.0, maxval=100.0)
     cached = jax.random.bernoulli(ks[4], 0.5, (n,))
     f, idx, val = ranking_scores(lam, z, resid, sizes, cached, omega=omega,
-                                 block=256)
+                                 block=256, interpret=True)
     f_ref, idx_ref, val_ref = ref.ranking_scores_ref(lam, z, resid, sizes,
                                                      cached, omega)
     np.testing.assert_allclose(np.asarray(f), np.asarray(f_ref), rtol=1e-5)
@@ -156,7 +161,8 @@ def test_ranking_victim_order_matches_ref(n, top):
     sizes = jax.random.uniform(ks[3], (n,), minval=1.0, maxval=100.0)
     cached = jax.random.bernoulli(ks[4], 0.5, (n,))
     f, idx, vals = ranking_victim_order(lam, z, resid, sizes, cached,
-                                        omega=1.0, top=top, block=256)
+                                        omega=1.0, top=top, block=256,
+                                        interpret=True)
     f_ref, _, _ = ref.ranking_scores_ref(lam, z, resid, sizes, cached, 1.0)
     np.testing.assert_allclose(np.asarray(f), np.asarray(f_ref), rtol=1e-5)
     # the order must equal the oracle's order over the KERNEL's own scores
@@ -180,7 +186,8 @@ def test_ranking_victim_order_sparse_cache_emits_inf_sentinels():
     sizes = jnp.full((n,), 2.0)
     cached = jnp.zeros((n,), bool).at[jnp.asarray([0, 9])].set(True)
     f, idx, vals = ranking_victim_order(lam, z, resid, sizes, cached,
-                                        omega=1.0, top=8, block=128)
+                                        omega=1.0, top=8, block=128,
+                                        interpret=True)
     v = np.asarray(vals)
     assert np.isfinite(v[:2]).all()
     assert set(np.asarray(idx)[:2]) == {0, 9}
@@ -220,7 +227,7 @@ def test_ranking_scores_agrees_with_core_ranking():
     sizes = jax.random.uniform(ks[3], (n,), minval=1.0, maxval=50.0)
     # the kernel takes R as an input; core's default estimator is R = 1/lam
     f_k, _, _ = ranking_scores(lam, z, 1.0 / lam, sizes,
-                               jnp.ones(n, bool), omega=1.0)
+                               jnp.ones(n, bool), omega=1.0, interpret=True)
     o = ObjStats(
         cached=jnp.ones(n, bool), in_flight=jnp.zeros(n, bool),
         complete_t=jnp.zeros(n), issue_t=jnp.zeros(n),

@@ -1,5 +1,5 @@
-"""Mesh builders (repro.launch.mesh): axis names, AxisType fallback, and
-the import-side-effect-free contract.
+"""Mesh builders (repro.launch.mesh): axis names, axis types, and the
+import-side-effect-free contract.
 
 ``make_production_mesh`` needs 256+ devices, so its axis wiring is checked
 against a capturing stand-in for ``jax.make_mesh`` rather than by building
@@ -11,10 +11,10 @@ regression test, since an in-process jax is already initialized.
 import os
 import subprocess
 import sys
-import types
 
 import jax
 import pytest
+from jax.sharding import AxisType
 
 from repro.launch import mesh as mesh_mod
 
@@ -41,21 +41,11 @@ def test_production_mesh_axis_names(capture_make_mesh):
     assert (s2, a2) == ((2, 16, 16), ("pod", "data", "model"))
 
 
-def test_axis_type_fallback_old_jax(capture_make_mesh, monkeypatch):
-    """Old jax (no jax.sharding.AxisType): make_mesh must be called without
-    the axis_types kwarg it doesn't accept."""
-    monkeypatch.setattr(mesh_mod, "AxisType", None)
-    mesh_mod.make_local_mesh()
-    _, _, kw = capture_make_mesh[0]
-    assert kw == {}
-
-
-def test_axis_type_forwarded_new_jax(capture_make_mesh, monkeypatch):
-    monkeypatch.setattr(mesh_mod, "AxisType",
-                        types.SimpleNamespace(Auto="auto"))
+def test_axis_type_forwarded_new_jax(capture_make_mesh):
+    """Every mesh axis is built as an Auto axis (sharding left to XLA)."""
     mesh_mod.make_production_mesh(multi_pod=True)
     _, axes, kw = capture_make_mesh[0]
-    assert kw == {"axis_types": ("auto",) * len(axes)}
+    assert kw == {"axis_types": (AxisType.Auto,) * len(axes)}
 
 
 def test_local_mesh_builds_on_one_device():
@@ -96,8 +86,7 @@ def test_import_performs_no_device_query():
         "assert jax.device_count() == 4, jax.device_count()\n"
         "print('DEVICES', jax.device_count())\n")
     env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("XLA_FLAGS", None)     # the child inherits the CPU platform
     proc = subprocess.run([sys.executable, "-c", child, SRC],
                           capture_output=True, text=True, timeout=300,
                           env=env)

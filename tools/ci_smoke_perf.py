@@ -205,12 +205,8 @@ def run_slots_smoke(rss_ceiling_mb: float,
     cmd = [sys.executable, "-m", "benchmarks.probe_memory",
            "--simstate-child", str(SLOTS_SMOKE_KEYS), "slots",
            "--requests", str(SLOTS_SMOKE_REQUESTS)]
-    import os
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    proc = subprocess.run(cmd, cwd=REPO_ROOT, env=env,
-                          capture_output=True, text=True,
-                          timeout=timeout_s)
+    proc = subprocess.run(cmd, cwd=REPO_ROOT, capture_output=True,
+                          text=True, timeout=timeout_s)
     marked = [ln for ln in proc.stdout.splitlines()
               if ln.startswith("SIMSTATE ")]
     if proc.returncode != 0 or not marked:
