@@ -40,6 +40,7 @@ import numpy as np
 from .distributions import Exponential
 from .ranking import (POLICIES, Policy, PolicyParams, agg_mean_hat_at,
                       epi_stochastic_vacdh, lambda_hat_at, make_substrate)
+from .spans import scope, span
 from .state import (ObjStats, SimState, SlotState, SlotView, init_slot_state,
                     init_state, kahan_add, lane_add, lane_set, onehot_add,
                     onehot_set, shift_times, slot_home, slot_probe,
@@ -454,7 +455,8 @@ def _commit_one(b: _Behavior, p: PolicyParams, estimate_z: bool,
 
     # --- fused rank-and-select, gated on the commit needing space --------
     def rank_select():
-        return b.select(o, sizes, t_c, top)
+        with scope("rank_select"):
+            return b.select(o, sizes, t_c, top)
 
     def skip_select():
         return (jnp.zeros((n,), jnp.float32), *_empty_order(top))
@@ -483,9 +485,10 @@ def _commit_one(b: _Behavior, p: PolicyParams, estimate_z: bool,
         return cached, free, clock, can, nev, k + 1
 
     if top:
-        cached, free, gd_clock, fit_ok, n_ev, _ = jax.lax.while_loop(
-            cond1, body1, (o.cached, state.free, gd_clock, admit_ok,
-                           state.n_evictions, jnp.int32(0)))
+        with scope("evict"):
+            cached, free, gd_clock, fit_ok, n_ev, _ = jax.lax.while_loop(
+                cond1, body1, (o.cached, state.free, gd_clock, admit_ok,
+                               state.n_evictions, jnp.int32(0)))
     else:       # evict_top=0: the legacy graph — phase 2 does all the work
         cached, free, fit_ok, n_ev = (o.cached, state.free, admit_ok,
                                       state.n_evictions)
@@ -508,8 +511,9 @@ def _commit_one(b: _Behavior, p: PolicyParams, estimate_z: bool,
                      jnp.where(can, jnp.maximum(clock, vr[v]), clock), clock)
         return cached, free, clock, can, nev
 
-    cached, free, gd_clock, fit_ok, n_ev = jax.lax.while_loop(
-        cond2, body2, (cached, free, gd_clock, fit_ok, n_ev))
+    with scope("evict"):
+        cached, free, gd_clock, fit_ok, n_ev = jax.lax.while_loop(
+            cond2, body2, (cached, free, gd_clock, fit_ok, n_ev))
 
     do_admit = admit_ok & fit_ok & (free >= s_j)
     cached = b.cond_set_at(cached, j, do_admit, True)
@@ -521,6 +525,7 @@ def _commit_one(b: _Behavior, p: PolicyParams, estimate_z: bool,
                           n_evictions=n_ev)
 
 
+@scope("commit")
 def _commit_due(b: _Behavior, p: PolicyParams, estimate_z: bool,
                 state: SimState, sizes: jax.Array, t, ids=None) -> SimState:
     """Commit every outstanding fetch with ``complete_t <= t``, in
@@ -533,6 +538,7 @@ def _commit_due(b: _Behavior, p: PolicyParams, estimate_z: bool,
         state)
 
 
+@scope("serve")
 def _serve(b: _Behavior, p: PolicyParams, state: SimState,
            sizes: jax.Array, t, i, z_realized, valid=True):
     """Serve the request (t, i); z_realized is used only if it's a miss.
@@ -957,36 +963,49 @@ def simulate_stream(stream: RequestStream, capacity: float,
         return _simulate_stream_slots(stream, capacity, policy, params, key,
                                       estimate_z, score_mode, chunk_size,
                                       rebase, n_slots, slot_seed, prefetch)
-    if n_slots is not None:
-        raise ValueError("n_slots applies only with state_mode='slots'")
-    times64 = np.asarray(stream.times, np.float64)
-    objs = np.asarray(stream.objs, np.int32)
-    z_draw = np.asarray(stream.z_draw, np.float32)
-    sizes = jnp.asarray(stream.sizes, jnp.float32)
-    # state.key is donated with the rest of the carry — keep the caller's
-    # key array alive by seeding the state with a copy.
-    state = init_state(stream.n_objects, jnp.float32(capacity),
-                       jnp.asarray(key).copy(),
-                       jnp.asarray(stream.z_mean, jnp.float32))
+    with span("stream.init"):
+        if n_slots is not None:
+            raise ValueError("n_slots applies only with state_mode='slots'")
+        times64 = np.asarray(stream.times, np.float64)
+        objs = np.asarray(stream.objs, np.int32)
+        z_draw = np.asarray(stream.z_draw, np.float32)
+        sizes = jnp.asarray(stream.sizes, jnp.float32)
+        # state.key is donated with the rest of the carry — keep the
+        # caller's key array alive by seeding the state with a copy.
+        state = init_state(stream.n_objects, jnp.float32(capacity),
+                           jnp.asarray(key).copy(),
+                           jnp.asarray(stream.z_mean, jnp.float32))
 
     def dispatch(state, chunk):
         t, i, z, valid, delta = chunk
-        return _chunk_step_jit(state, t, i, z, valid, delta, sizes, params,
-                               policy, estimate_z, score_mode, evict_top)
+        with span("stream.dispatch"):
+            return _chunk_step_jit(state, t, i, z, valid, delta, sizes,
+                                   params, policy, estimate_z, score_mode,
+                                   evict_top)
 
+    # the builder yields exactly n_chunks chunks; pulling no further keeps
+    # every prep span around real work (a suspended generator holds none)
     chunks = _stream_chunks(times64, objs, z_draw, chunk_size, rebase)
+    n_chunks = -(-max(times64.shape[0], 1) // chunk_size)
+
+    def prep():
+        with span("stream.prep"):
+            return next(chunks)
+
     if prefetch:
         # one-chunk lookahead: pull chunk k+1 from the builder (host slice
         # + async device_put) BEFORE dispatching chunk k's scan, so the
         # prep/transfer of the next chunk overlaps the current execution
         # even on backends whose dispatch is not fully asynchronous.
-        pending = next(chunks, None)
-        while pending is not None:
-            cur, pending = pending, next(chunks, None)
+        pending = prep()
+        for k in range(n_chunks):
+            cur = pending
+            if k + 1 < n_chunks:
+                pending = prep()
             state = dispatch(state, cur)
     else:
-        for cur in chunks:
-            state = dispatch(state, cur)
+        for _ in range(n_chunks):
+            state = dispatch(state, prep())
     return _result_of_state(state)
 
 

@@ -48,6 +48,7 @@ from .simulator import (_COMMIT_MODES, SimResult, _behavior_multi,
                         _simulate_impl, _simulate_multi_impl,
                         batched_commit_mode, batched_update_mode,
                         resolve_score_mode)
+from .spans import span
 from .state import init_state
 from .trace import Trace
 
@@ -425,96 +426,108 @@ def sweep_grid(traces, capacities, policies,
     ``[T, L, P, C, S]``-shaped.  Each point is bitwise identical to the
     corresponding per-point :func:`simulate` call.
     """
-    trace_list = [traces] if isinstance(traces, Trace) else list(traces)
-    single, policy_names, params_list = _check_axes(policies, params)
-    caps = jnp.atleast_1d(jnp.asarray(capacities, jnp.float32))
-    seeds = [int(s) for s in jnp.atleast_1d(jnp.asarray(seeds))]
-    if state_mode != "dense":
-        if state_mode == "slots":
+    with span("sweep.prologue"):
+        trace_list = [traces] if isinstance(traces, Trace) else list(traces)
+        single, policy_names, params_list = _check_axes(policies, params)
+        caps = jnp.atleast_1d(jnp.asarray(capacities, jnp.float32))
+        seeds = [int(s) for s in jnp.atleast_1d(jnp.asarray(seeds))]
+        if state_mode != "dense":
+            if state_mode == "slots":
+                raise ValueError(
+                    "state_mode='slots' is not supported by sweep_grid — "
+                    "the sweep engine's lane machinery (and the device "
+                    "fabric) batch dense [N]-state lane axes only; run "
+                    "slot-table replays through simulate / simulate_stream "
+                    "/ simulate_chunked")
             raise ValueError(
-                "state_mode='slots' is not supported by sweep_grid — the "
-                "sweep engine's lane machinery (and the device fabric) "
-                "batch dense [N]-state lane axes only; run slot-table "
-                "replays through simulate / simulate_stream / "
-                "simulate_chunked")
-        raise ValueError(f"state_mode={state_mode!r}; expected 'dense'")
+                f"state_mode={state_mode!r}; expected 'dense'")
 
-    fabric_mesh = None
-    if devices is not None or mesh is not None:
-        from repro.launch.fabric import fabric_lane_multiple, resolve_fabric
-        fabric_mesh = resolve_fabric(devices, mesh)
+        fabric_mesh = None
+        if devices is not None or mesh is not None:
+            from repro.launch.fabric import (fabric_lane_multiple,
+                                             resolve_fabric)
+            fabric_mesh = resolve_fabric(devices, mesh)
 
-    if commit_mode is not None and commit_mode not in _COMMIT_MODES:
-        raise ValueError(f"commit_mode={commit_mode!r}; expected None or "
-                         f"one of {_COMMIT_MODES}")
-    if commit_mode == "compact":
-        if single:
-            raise ValueError(
-                "commit_mode='compact' applies to multi-policy grids (it "
-                "groups lanes by policy under statically specialized "
-                "graphs); a single-policy grid is already statically "
-                "specialized")
-        if fabric_mesh is not None:
-            raise ValueError(
-                "commit_mode='compact' is not supported with devices/mesh "
-                "— the fabric shard_maps one lockstep lane body over "
-                "device shards (the grouped dispatch splits the very lane "
-                "axis the fabric shards); drop devices=/mesh= or pass "
-                "commit_mode='lockstep'")
-    if commit_mode is None:
-        # compact pays at large universes where the per-commit substrate
-        # dominates; single-policy bodies and fabric shards stay lockstep
-        commit_mode = ("lockstep" if single or fabric_mesh is not None
-                       else batched_commit_mode(trace_list[0].n_objects))
+        if commit_mode is not None and commit_mode not in _COMMIT_MODES:
+            raise ValueError(f"commit_mode={commit_mode!r}; expected None "
+                             f"or one of {_COMMIT_MODES}")
+        if commit_mode == "compact":
+            if single:
+                raise ValueError(
+                    "commit_mode='compact' applies to multi-policy grids "
+                    "(it groups lanes by policy under statically "
+                    "specialized graphs); a single-policy grid is already "
+                    "statically specialized")
+            if fabric_mesh is not None:
+                raise ValueError(
+                    "commit_mode='compact' is not supported with "
+                    "devices/mesh — the fabric shard_maps one lockstep lane "
+                    "body over device shards (the grouped dispatch splits "
+                    "the very lane axis the fabric shards); drop "
+                    "devices=/mesh= or pass commit_mode='lockstep'")
+        if commit_mode is None:
+            # compact pays at large universes where the per-commit
+            # substrate dominates; single-policy bodies and fabric shards
+            # stay lockstep
+            commit_mode = ("lockstep" if single or fabric_mesh is not None
+                           else batched_commit_mode(trace_list[0].n_objects))
 
-    tstack = _stack(trace_list)
-    L, P, C, S = len(policy_names), len(params_list), caps.shape[0], len(seeds)
-    lflat, pflat, (cflat,), kflat, G = _flatten_lanes(
-        policy_names, params_list, [caps], seeds, lane_bucket,
-        multiple=(fabric_lane_multiple(fabric_mesh) if fabric_mesh is not None
-                  else 1))
+        tstack = _stack(trace_list)
+        L, P, C, S = (len(policy_names), len(params_list), caps.shape[0],
+                      len(seeds))
+        lflat, pflat, (cflat,), kflat, G = _flatten_lanes(
+            policy_names, params_list, [caps], seeds, lane_bucket,
+            multiple=(fabric_lane_multiple(fabric_mesh)
+                      if fabric_mesh is not None else 1))
 
-    if not single and resolve_score_mode(use_kernel) != "rank":
-        raise ValueError("use_kernel is only supported for single-policy "
-                         "sweeps (the kernel specializes eq. 16)")
-    # the concrete lane->policy map, passed statically so the compact
-    # dispatch can group lanes at trace time (None under lockstep so the
-    # jit cache key does not fragment on it)
-    lane_policy = (tuple(int(x) for x in np.asarray(lflat))
-                   if commit_mode == "compact" else None)
-    if update is None:
-        # point scatters for an unbatched single lane; once lanes batch,
-        # the N-dependent batched default (DESIGN.md §11)
-        update = batched_update_mode(trace_list[0].n_objects) \
-            if (not single or cflat.shape[0] > 1) else "scatter"
-    if chunk_size is not None:
-        if fabric_mesh is not None:
-            raise ValueError(
-                "chunk_size is not supported with devices/mesh yet — the "
-                "chunked grid carries donated per-lane states across a "
-                "host-side loop, which the fabric does not shard")
-        res = _run_sweep_chunked(tstack, cflat, kflat, lflat, pflat, single,
-                                 policy_names, estimate_z,
-                                 resolve_score_mode(use_kernel),
-                                 update, chunk_size, commit_mode,
-                                 lane_policy)
-    elif fabric_mesh is not None:
-        from repro.launch.fabric import fabric_sweep_multi, fabric_sweep_single
-        if single:
-            res = fabric_sweep_single(fabric_mesh, tstack, cflat, kflat,
-                                      pflat, policy_names[0], estimate_z,
-                                      resolve_score_mode(use_kernel), update)
+        if not single and resolve_score_mode(use_kernel) != "rank":
+            raise ValueError("use_kernel is only supported for "
+                             "single-policy sweeps (the kernel specializes "
+                             "eq. 16)")
+        # the concrete lane->policy map, passed statically so the compact
+        # dispatch can group lanes at trace time (None under lockstep so
+        # the jit cache key does not fragment on it)
+        lane_policy = (tuple(int(x) for x in np.asarray(lflat))
+                       if commit_mode == "compact" else None)
+        if update is None:
+            # point scatters for an unbatched single lane; once lanes
+            # batch, the N-dependent batched default (DESIGN.md §11)
+            update = batched_update_mode(trace_list[0].n_objects) \
+                if (not single or cflat.shape[0] > 1) else "scatter"
+    with span("sweep.dispatch"):
+        if chunk_size is not None:
+            if fabric_mesh is not None:
+                raise ValueError(
+                    "chunk_size is not supported with devices/mesh yet — "
+                    "the chunked grid carries donated per-lane states "
+                    "across a host-side loop, which the fabric does not "
+                    "shard")
+            res = _run_sweep_chunked(tstack, cflat, kflat, lflat, pflat,
+                                     single, policy_names, estimate_z,
+                                     resolve_score_mode(use_kernel),
+                                     update, chunk_size, commit_mode,
+                                     lane_policy)
+        elif fabric_mesh is not None:
+            from repro.launch.fabric import (fabric_sweep_multi,
+                                             fabric_sweep_single)
+            if single:
+                res = fabric_sweep_single(fabric_mesh, tstack, cflat, kflat,
+                                          pflat, policy_names[0],
+                                          estimate_z,
+                                          resolve_score_mode(use_kernel),
+                                          update)
+            else:
+                res = fabric_sweep_multi(fabric_mesh, tstack, cflat, kflat,
+                                         lflat, pflat, policy_names,
+                                         estimate_z, update)
+        elif single:
+            res = _sweep_single(tstack, cflat, kflat, pflat, policy_names[0],
+                                estimate_z, resolve_score_mode(use_kernel),
+                                update)
         else:
-            res = fabric_sweep_multi(fabric_mesh, tstack, cflat, kflat,
-                                     lflat, pflat, policy_names, estimate_z,
-                                     update)
-    elif single:
-        res = _sweep_single(tstack, cflat, kflat, pflat, policy_names[0],
-                            estimate_z, resolve_score_mode(use_kernel),
-                            update)
-    else:
-        res = _sweep_multi(tstack, cflat, kflat, lflat, pflat, policy_names,
-                           estimate_z, update, commit_mode, lane_policy)
+            res = _sweep_multi(tstack, cflat, kflat, lflat, pflat,
+                               policy_names, estimate_z, update, commit_mode,
+                               lane_policy)
     res = SimResult(*(x[:, :G].reshape((len(trace_list), L, P, C, S))
                       for x in res))
     return SweepGrid(res, policy_names, tuple(params_list), caps,
