@@ -34,6 +34,7 @@ identically-shaped traces are passed.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Sequence
 
 import jax
@@ -322,40 +323,53 @@ def _check_axes(policies, params):
     return single, policy_names, params_list
 
 
-def _flatten_lanes(policy_names, params_list, cap_arrays, seeds,
-                   lane_bucket, multiple: int = 1):
-    """Flatten policies x params x capacity-axes x seeds into padded lanes.
+def _lane_index(dims, n_pad: int) -> np.ndarray:
+    """The lane grid's index vectors on the host: row ``a`` of the
+    ``[len(dims), n_pad]`` result is each lane's position on axis ``a``,
+    lanes in row-major (meshgrid ``'ij'``) order, so lanes are
+    policy-major.  Pad lanes index 0 on every axis, i.e. they replicate
+    lane 0."""
+    idx = np.indices(dims, np.int32).reshape(len(dims), -1)
+    return np.pad(idx, ((0, 0), (0, n_pad - idx.shape[1])))
 
-    Returns ``(lflat, pflat, capflats, kflat, G)`` where the flats are
-    bucket-padded (repeats of lane 0) and ``G`` is the true lane count to
-    slice back out.  Shared by the single-tier and hierarchy grids so the
-    flatten/pad pipeline cannot drift between them.  ``multiple`` rounds
-    the padded lane count up to a device-count multiple for the sweep
-    fabric (DESIGN.md §13) — pad lanes are dead lanes either way: replicas
-    of lane 0 whose results are sliced off, never interacting with real
+
+@functools.partial(jax.jit, static_argnames=("dims", "n_pad"))
+def _lane_setup(traces, params, cap_arrays, seeds, dims, n_pad):
+    """Every device input of a grid in one program: the stacked traces,
+    then the lane-flattened policy index, params, capacity axes and PRNG
+    keys.  Compiles once per grid shape (``dims``, ``n_pad``, the params'
+    structure, the trace count and shape)."""
+    idx = _lane_index(dims, n_pad)
+    pflat = jax.tree.map(lambda x: x[idx[1]], _stack(params))
+    capflats = [c[i] for c, i in zip(cap_arrays, idx[2:-1])]
+    kflat = jax.vmap(jax.random.key)(seeds)[idx[-1]]
+    return _stack(traces), jnp.asarray(idx[0]), pflat, capflats, kflat
+
+
+def _flatten_lanes(trace_list, policy_names, params_list, cap_arrays, seeds,
+                   lane_bucket, multiple: int = 1):
+    """Stack the traces and flatten policies x params x capacity-axes x
+    seeds into padded lanes.
+
+    Returns ``(tstack, lflat, pflat, capflats, kflat, G, lanes)`` where
+    the flats are bucket-padded (repeats of lane 0), ``G`` is the true
+    lane count to slice back out and ``lanes`` is ``lflat`` on the host.
+    Shared by the single-tier and hierarchy grids so the flatten/pad
+    pipeline cannot drift between them.  ``multiple`` rounds the padded
+    lane count up to a device-count multiple for the sweep fabric
+    (DESIGN.md §13) — pad lanes are dead lanes either way: replicas of
+    lane 0 whose results are sliced off, never interacting with real
     lanes, so padding is invisible in results (tests/test_fabric.py).
     """
-    dims = [len(policy_names), len(params_list),
-            *[c.shape[0] for c in cap_arrays], len(seeds)]
-    grids = jnp.meshgrid(*[jnp.arange(d) for d in dims], indexing="ij")
-    lflat = grids[0].ravel()
-    pstack = _stack(params_list)
-    pflat = jax.tree.map(lambda x: x[grids[1].ravel()], pstack)
-    capflats = [c[g.ravel()] for c, g in zip(cap_arrays, grids[2:-1])]
-    keys = jnp.stack([jax.random.key(s) for s in seeds])
-    kflat = keys[grids[-1].ravel()]
-
-    G = 1
-    for d in dims:
-        G *= d
+    dims = (len(policy_names), len(params_list),
+            *[c.shape[0] for c in cap_arrays], len(seeds))
+    G = math.prod(dims)
     Gpad = _bucket(_bucket(G, lane_bucket), multiple)
-    if Gpad > G:
-        ext = lambda x: jnp.concatenate(
-            [x, jnp.broadcast_to(x[:1], (Gpad - G,) + x.shape[1:])])
-        lflat, kflat = ext(lflat), ext(kflat)
-        capflats = [ext(c) for c in capflats]
-        pflat = jax.tree.map(ext, pflat)
-    return lflat, pflat, capflats, kflat, G
+    tstack, lflat, pflat, capflats, kflat = _lane_setup(
+        tuple(trace_list), tuple(params_list), tuple(cap_arrays),
+        np.asarray(seeds, np.int64), dims=dims, n_pad=Gpad)
+    lanes = _lane_index(dims, Gpad)[0]
+    return tstack, lflat, pflat, capflats, kflat, G, lanes
 
 
 def sweep_grid(traces, capacities, policies,
@@ -430,7 +444,7 @@ def sweep_grid(traces, capacities, policies,
         trace_list = [traces] if isinstance(traces, Trace) else list(traces)
         single, policy_names, params_list = _check_axes(policies, params)
         caps = jnp.atleast_1d(jnp.asarray(capacities, jnp.float32))
-        seeds = [int(s) for s in jnp.atleast_1d(jnp.asarray(seeds))]
+        seeds = np.atleast_1d(np.asarray(seeds, np.int64)).tolist()
         if state_mode != "dense":
             if state_mode == "slots":
                 raise ValueError(
@@ -472,13 +486,12 @@ def sweep_grid(traces, capacities, policies,
             commit_mode = ("lockstep" if single or fabric_mesh is not None
                            else batched_commit_mode(trace_list[0].n_objects))
 
-        tstack = _stack(trace_list)
         L, P, C, S = (len(policy_names), len(params_list), caps.shape[0],
                       len(seeds))
-        lflat, pflat, (cflat,), kflat, G = _flatten_lanes(
-            policy_names, params_list, [caps], seeds, lane_bucket,
-            multiple=(fabric_lane_multiple(fabric_mesh)
-                      if fabric_mesh is not None else 1))
+        tstack, lflat, pflat, (cflat,), kflat, G, lanes = _flatten_lanes(
+            trace_list, policy_names, params_list, [caps], seeds,
+            lane_bucket, multiple=(fabric_lane_multiple(fabric_mesh)
+                                   if fabric_mesh is not None else 1))
 
         if not single and resolve_score_mode(use_kernel) != "rank":
             raise ValueError("use_kernel is only supported for "
@@ -487,8 +500,8 @@ def sweep_grid(traces, capacities, policies,
         # the concrete lane->policy map, passed statically so the compact
         # dispatch can group lanes at trace time (None under lockstep so
         # the jit cache key does not fragment on it)
-        lane_policy = (tuple(int(x) for x in np.asarray(lflat))
-                       if commit_mode == "compact" else None)
+        lane_policy = (tuple(lanes.tolist()) if commit_mode == "compact"
+                       else None)
         if update is None:
             # point scatters for an unbatched single lane; once lanes
             # batch, the N-dependent batched default (DESIGN.md §11)
@@ -644,18 +657,17 @@ def sweep_hier_grid(traces, n_shards: int, l1_capacities, l2_capacities,
         l2_params = PolicyParams()
     c1 = jnp.atleast_1d(jnp.asarray(l1_capacities, jnp.float32))
     c2 = jnp.atleast_1d(jnp.asarray(l2_capacities, jnp.float32))
-    seeds = [int(s) for s in jnp.atleast_1d(jnp.asarray(seeds))]
+    seeds = np.atleast_1d(np.asarray(seeds, np.int64)).tolist()
 
     fabric_mesh = None
     if devices is not None or mesh is not None:
         from repro.launch.fabric import fabric_lane_multiple, resolve_fabric
         fabric_mesh = resolve_fabric(devices, mesh)
 
-    tstack = _stack(trace_list)
     L, P, C1, C2, S = (len(policy_names), len(params_list), c1.shape[0],
                        c2.shape[0], len(seeds))
-    lflat, pflat, (c1flat, c2flat), kflat, G = _flatten_lanes(
-        policy_names, params_list, [c1, c2], seeds, lane_bucket,
+    tstack, lflat, pflat, (c1flat, c2flat), kflat, G, _ = _flatten_lanes(
+        trace_list, policy_names, params_list, [c1, c2], seeds, lane_bucket,
         multiple=(fabric_lane_multiple(fabric_mesh) if fabric_mesh is not None
                   else 1))
 

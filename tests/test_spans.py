@@ -28,16 +28,22 @@ def _trace(n_requests=300, n_objects=20):
     return synthetic_trace(jax.random.key(0), spec)
 
 
-def _host_spans(trace_dir) -> collections.Counter:
-    """Count of every ``repro.*`` event on the host planes of the newest
-    trace under ``trace_dir``."""
+def _host_events(trace_dir):
+    """``(name, start_ns, end_ns)`` of every event on the host planes of
+    the newest trace under ``trace_dir``."""
     path = sorted(glob.glob(str(trace_dir / "plugins" / "profile" / "*"
                                 / "*.xplane.pb")))[-1]
     data = ProfileData.from_file(path)
-    return collections.Counter(
-        e.name for plane in data.planes if plane.name.startswith("/host")
-        for line in plane.lines for e in line.events
-        if e.name.startswith(PREFIX))
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in data.planes if plane.name.startswith("/host")
+            for line in plane.lines for e in line.events]
+
+
+def _host_spans(trace_dir) -> collections.Counter:
+    """Count of every ``repro.*`` event on the host planes of the newest
+    trace under ``trace_dir``."""
+    return collections.Counter(n for n, _, _ in _host_events(trace_dir)
+                               if n.startswith(PREFIX))
 
 
 @pytest.mark.parametrize("prefetch", [True, False])
@@ -62,6 +68,29 @@ def test_sweep_spans_once_per_call(tmp_path):
         jax.block_until_ready(run().result)
     assert _host_spans(tmp_path) == {"repro.sweep.prologue": 1,
                                      "repro.sweep.dispatch": 1}
+
+
+# the CPU runtime's host event for one launch of a compiled program
+CPU_EXECUTE = "PjRtCpuExecutable::Execute"
+
+
+def test_sweep_prologue_launches_one_lane_setup(tmp_path):
+    """A roster-shaped grid (11 policies x 1 x 1 x 1) launches at most 3
+    compiled programs while the host is in ``repro.sweep.prologue``: the
+    lanes are built by one program, not per leaf."""
+    trace = _trace()
+    names = ["lru", "lfu", "lhd", "adaptsize", "lru_mad", "lhd_mad", "lac",
+             "cala", "vacdh", "lrb_lite", "stoch_vacdh"]
+    run = lambda: sweep_grid(trace, 60.0, names, [PolicyParams(omega=1.0)],
+                             estimate_z=True)
+    jax.block_until_ready(run().result)
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(run().result)
+    events = _host_events(tmp_path)
+    (lo, hi), = [(s, e) for n, s, e in events
+                 if n == PREFIX + "sweep.prologue"]
+    launches = [s for n, s, _ in events if n == CPU_EXECUTE and lo <= s < hi]
+    assert 1 <= len(launches) <= 3
 
 
 def test_names_come_from_the_tuples():

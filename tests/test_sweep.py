@@ -204,3 +204,109 @@ def test_kernel_scored_single_policy_sweep_matches():
         np.asarray(g_r.result.total_latency), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(g_k.result.n_evictions),
                                   np.asarray(g_r.result.n_evictions))
+
+
+# ---------------------------------------------------------------------------
+# The lane set-up (sweep._flatten_lanes): one compiled program builds every
+# grid input; each must equal the eager per-call construction it replaced.
+# ---------------------------------------------------------------------------
+ROSTER = ("lru", "lfu", "lhd", "adaptsize", "lru_mad", "lhd_mad", "lac",
+          "cala", "vacdh", "lrb_lite", "stoch_vacdh")
+
+
+def _eager_lanes(policy_names, params_list, cap_arrays, seeds, lane_bucket,
+                 multiple=1):
+    """The eager lane construction, kept as it was before the lanes were
+    built in one program: a stack, meshgrid and gather per call."""
+    stack = lambda ts: jax.tree.map(lambda *xs: jnp.stack(xs), *ts)
+    bucket = lambda n, b: -(-n // b) * b if b else n
+    dims = [len(policy_names), len(params_list),
+            *[c.shape[0] for c in cap_arrays], len(seeds)]
+    grids = jnp.meshgrid(*[jnp.arange(d) for d in dims], indexing="ij")
+    lflat = grids[0].ravel()
+    pflat = jax.tree.map(lambda x: x[grids[1].ravel()], stack(params_list))
+    capflats = [c[g.ravel()] for c, g in zip(cap_arrays, grids[2:-1])]
+    keys = jnp.stack([jax.random.key(s) for s in seeds])
+    kflat = keys[grids[-1].ravel()]
+    G = int(np.prod(dims))
+    Gpad = bucket(bucket(G, lane_bucket), multiple)
+    if Gpad > G:
+        ext = lambda x: jnp.concatenate(
+            [x, jnp.broadcast_to(x[:1], (Gpad - G,) + x.shape[1:])])
+        lflat, kflat = ext(lflat), ext(kflat)
+        capflats = [ext(c) for c in capflats]
+        pflat = jax.tree.map(ext, pflat)
+    lane_policy = tuple(int(x) for x in np.asarray(lflat))
+    return lflat, pflat, capflats, kflat, G, lane_policy
+
+
+def _assert_same_arrays(got, want):
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert (g.dtype, g.shape, g.weak_type) == \
+            (w.dtype, w.shape, w.weak_type)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+LANE_SEEDS = (0, 2**31 - 1, 2**31 + 5, 2**32 + 3, -4)
+
+
+def _lane_grid(kind, seed):
+    """(traces, policies, params, capacity axes, seeds, bucket, multiple)
+    of one grid shape the API accepts."""
+    caps = lambda *c: jnp.asarray(np.asarray(c, np.float32))
+    if kind == "roster":
+        return ([_trace()], ROSTER, [PolicyParams(omega=1.0)], [caps(500.0)],
+                [seed], None, 1)
+    if kind == "omega_cap_seed":
+        return ([_trace(), _trace(seed=1)], ("stoch_vacdh",),
+                [PolicyParams(omega=o) for o in (0.0, 0.5, 2.0)],
+                [caps(60.0, 150.0)], [seed, 7, 0], None, 1)
+    if kind == "lane_bucket":
+        return ([_trace()], ("lru", "stoch_vacdh"),
+                [PolicyParams(omega=o, dist=Erlang(k=2.0)) for o in (0., 2.)],
+                [caps(100.0)], [seed], 12, 1)
+    if kind == "fabric4":
+        return ([_trace()], ("lru", "lfu", "vacdh"), [PolicyParams()],
+                [caps(40.0, 80.0)], [seed], None, 4)
+    ht = make_hier_trace(_trace(), 2, hop_mean=0.002, route="hash")
+    return ([ht], ("lru", "stoch_vacdh"), [PolicyParams(omega=1.0)],
+            [caps(20.0, 40.0), caps(0.0, 90.0, 120.0)], [seed, 3], 8, 1)
+
+
+@pytest.mark.parametrize("seed", LANE_SEEDS)
+@pytest.mark.parametrize("kind", ["roster", "omega_cap_seed", "lane_bucket",
+                                  "fabric4", "hier_two_caps"])
+def test_lane_setup_matches_eager_construction(kind, seed):
+    """Lanes, params, capacities, keys, the true lane count and the host
+    lane->policy map equal the eager construction bit for bit, padding
+    and seeds past 32 bits included."""
+    from repro.core.sweep import _flatten_lanes
+    traces, names, params, caps, seeds, bucket, multiple = \
+        _lane_grid(kind, seed)
+    tstack, lflat, pflat, capflats, kflat, G, lanes = _flatten_lanes(
+        traces, names, params, caps, seeds, bucket, multiple)
+    want = _eager_lanes(names, params, caps, seeds, bucket, multiple)
+    _assert_same_arrays((lflat, pflat, capflats), want[:3])
+    assert kflat.dtype == want[3].dtype
+    _assert_same_arrays(jax.random.key_data(kflat),
+                        jax.random.key_data(want[3]))
+    assert G == want[4]
+    assert tuple(lanes.tolist()) == want[5]
+    _assert_same_arrays(tstack, jax.tree.map(lambda *xs: jnp.stack(xs),
+                                             *traces))
+
+
+def test_lane_setup_compiles_once_per_grid_shape():
+    """Another trace of the same shape reuses the compiled lane set-up;
+    another grid shape compiles one more."""
+    from repro.core.sweep import _lane_setup
+    _lane_setup.clear_cache()
+    names, params = ["lru", "stoch_vacdh"], [PolicyParams(omega=1.0)]
+    sweep_grid(_trace(seed=0), 100.0, names, params)
+    assert _lane_setup._cache_size() == 1
+    sweep_grid(_trace(seed=1), 100.0, names, params)
+    assert _lane_setup._cache_size() == 1
+    sweep_grid(_trace(seed=1), [60.0, 100.0], names, params)
+    assert _lane_setup._cache_size() == 2
