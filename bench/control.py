@@ -35,7 +35,7 @@ def readings(cell, seed: int, calls: int, workers=None) -> dict:
     ref = reference_answers(drv, segments, None, workers)
     answers = [(k, {f: [low[(k % drv.n_segments, lane)][f]
                         for lane in range(len(drv.lanes))]
-                    for f in drivers.FIELDS}) for k in range(calls)]
+                    for f in drv.fields}) for k in range(calls)]
     return compare(drv, answers, ref, cell.traffic["limits"])
 
 

@@ -9,12 +9,17 @@ A traffic mix names its driver:
   call per segment over every (policy, omega, capacity) lane (a user
   comparing policies or sweeping hyperparameters).
 
+Any other name is the file ``bench/callers/<name>.py`` and its class
+``Driver``, a subclass of :class:`Segments` (``bench/cell.py``).
+
 The benchmark makes every request itself (``bench/generate.py``, the
 generator the configuration names); the program receives the arrays as a
 ``RequestStream`` or through ``make_trace``, with the benchmark's fetch
 draws.  Each call's answer is pulled to the host as numpy, one value per
 lane, and :meth:`jobs` says how the plain reference recomputes a
-segment's lanes.  A traffic mix also names the end-to-end metric its
+segment's lanes; a driver's ``fields`` are the answer fields pulled and
+compared, and its ``reference`` names the plain reference that
+recomputes them.  A traffic mix also names the end-to-end metric its
 calls' work rate is reported under (``rate_metric``).
 """
 from __future__ import annotations
@@ -22,6 +27,7 @@ from __future__ import annotations
 import numpy as np
 
 from bench import generate as G
+from bench.cell import BUILTIN_REFERENCE, load_plugin
 
 FIELDS = ("total_latency", "n_hits", "n_delayed", "n_misses", "n_evictions")
 
@@ -41,17 +47,20 @@ def _draws(unit):
     return BenchDraws()
 
 
-def _pull(result) -> dict:
+def _pull(result, fields) -> dict:
     import jax
-    host = jax.device_get([getattr(result, f) for f in FIELDS])
+    host = jax.device_get([getattr(result, f) for f in fields])
     return {f: np.asarray(v, np.float64).reshape(-1)
-            for f, v in zip(FIELDS, host)}
+            for f, v in zip(fields, host)}
 
 
-class _Segments:
-    """What both drivers share: consecutive segments that divide the
+class Segments:
+    """What every driver shares: consecutive segments that divide the
     configuration's trace, and set-up in two timed parts, the benchmark's
     own generation (numpy) and the program's ingest of it."""
+
+    fields = FIELDS
+    reference = BUILTIN_REFERENCE
 
     def __init__(self, config: dict, traffic: dict, seed: int):
         self.cfg, self.tr, self.seed = config, traffic, seed
@@ -94,7 +103,7 @@ class _Segments:
         return len(self.ref_in["sizes"])
 
 
-class Replay(_Segments):
+class Replay(Segments):
     """``simulate_stream`` over consecutive segments of a stream."""
 
     def __init__(self, config: dict, traffic: dict, seed: int):
@@ -129,7 +138,7 @@ class Replay(_Segments):
             stream, self.lanes[0][2], t["policy"], self.params, key=self.key,
             estimate_z=bool(t["estimate_z"]), use_kernel=t["use_kernel"],
             chunk_size=int(t["chunk_size"]),
-            state_mode=t.get("state_mode", "dense")))
+            state_mode=t.get("state_mode", "dense")), self.fields)
 
     def warm(self) -> None:
         """One whole chunk compiles (or loads) the one program every
@@ -151,7 +160,7 @@ class Replay(_Segments):
         return [dict(job, **(control or {}))]
 
 
-class Sweep(_Segments):
+class Sweep(Segments):
     """``sweep_grid`` over consecutive segments of a resident trace."""
 
     def __init__(self, config: dict, traffic: dict, seed: int):
@@ -202,7 +211,7 @@ class Sweep(_Segments):
                        self.policies, self.params, seeds=(self.coin_seed,),
                        estimate_z=bool(self.tr["estimate_z"]),
                        devices=self.devices).result
-        return _pull(r)
+        return _pull(r, self.fields)
 
     def jobs(self, segment: int, control: dict | None = None) -> list:
         b, sl = self.ref_in, self._slice(segment)
@@ -217,7 +226,9 @@ DRIVERS = {"replay": Replay, "sweep": Sweep}
 
 
 def build(config: dict, traffic: dict, seed: int):
+    """The driver a traffic mix names: a built-in, else the ``Driver`` of
+    ``bench/callers/<name>.py``."""
     kind = traffic["driver"]
-    if kind not in DRIVERS:
-        raise ValueError(f"unknown driver {kind!r}; known: {sorted(DRIVERS)}")
-    return DRIVERS[kind](config, traffic, seed)
+    cls = DRIVERS[kind] if kind in DRIVERS else load_plugin(
+        "driver", kind, DRIVERS).Driver
+    return cls(config, traffic, seed)

@@ -1,8 +1,8 @@
 """Request generation for the benchmark, from a seed alone (numpy).
 
-One general generator, named by a configuration's ``generator`` key
-(``bench/configs``), reads the deployment's laws from the configuration
-and the arrival process from the traffic mix (``bench/traffic``):
+A configuration names its generator (``generator``, ``bench/configs``),
+which reads the deployment's laws from the configuration and the arrival
+process from the traffic mix (``bench/traffic``).  The built-in one:
 
 * ``synthetic`` — requests over a fixed set of objects: Zipf popularity
   over ``n_objects``, sizes uniform between ``size_min`` and ``size_max``
@@ -22,10 +22,15 @@ where.  So runs of different seeds differ by the order of the work and
 not by its amount.  Every shuffle comes from its own child of
 ``numpy.random.SeedSequence(seed)``, so the same seed gives the same
 requests in any process, and nothing depends on Python's salted ``hash``.
+
+Any other generator name is the file ``bench/generators/<name>.py`` and
+its ``requests(cfg, traffic, seed)`` (``bench/cell.py``).
 """
 from __future__ import annotations
 
 import numpy as np
+
+from bench.cell import load_plugin
 
 _STREAMS = ("keys", "gaps", "fetch", "coins")
 
@@ -99,9 +104,10 @@ GENERATORS = {"synthetic": synthetic}
 
 
 def requests(cfg: dict, traffic: dict, seed: int) -> dict:
-    """The requests of a cell, from the generator its configuration names."""
+    """The requests of a cell, from the generator its configuration names:
+    a built-in, else ``bench/generators/<name>.py``."""
     name = cfg["generator"]
-    if name not in GENERATORS:
-        raise ValueError(f"unknown generator {name!r}; known: "
-                         f"{sorted(GENERATORS)}")
-    return GENERATORS[name](cfg, traffic, seed)
+    if name in GENERATORS:
+        return GENERATORS[name](cfg, traffic, seed)
+    return load_plugin("generator", name, GENERATORS).requests(
+        cfg, traffic, seed)

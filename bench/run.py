@@ -9,7 +9,8 @@ program through its entry points, warms every compiled shape up (all of
 it counted as set-up), then calls the program in a closed loop for
 ``--seconds``: each call starts when the previous one has returned and its
 answer has been pulled to the host.  After the window, every answer is
-compared with the plain reference (``bench/reference.py``) and the run
+compared with the plain reference (``bench/reference.py``, or the one
+the cell's driver names) and the run
 prints one JSON line: ``correct``, ``attempted``, ``failed``, ``metrics``
 (the cell's end-to-end metrics, or with ``--trace 1`` its per-layer
 metrics, read from a profiler trace of the window's first call),
@@ -109,28 +110,33 @@ def closed_loop(driver, seconds: float, spans: Spans, after_first=None):
 
 
 def reference_answers(driver, segments, control=None, workers=None):
-    """The plain reference's answer for every lane of ``segments``, in a
-    pool of processes that import only numpy."""
+    """The answer of the driver's plain reference for every lane of
+    ``segments``, in a pool of processes that import only numpy."""
+    import itertools
     import multiprocessing
     from concurrent.futures import ProcessPoolExecutor
 
-    from bench import reference
+    from bench.cell import reference_source, run_reference_job
+    source = reference_source(driver.reference)
     jobs = [(seg, lane, job) for seg in segments
             for lane, job in enumerate(driver.jobs(seg, control))]
     n = workers or max(1, min(len(jobs), (os.cpu_count() or 2) - 1))
     if n == 1:
-        outs = [reference.run_job(j) for *_, j in jobs]
+        outs = [run_reference_job(source, j) for *_, j in jobs]
     else:
         with ProcessPoolExecutor(
                 n, mp_context=multiprocessing.get_context("spawn")) as ex:
-            outs = list(ex.map(reference.run_job, [j for *_, j in jobs]))
+            outs = list(ex.map(run_reference_job, itertools.repeat(source),
+                               [j for *_, j in jobs]))
     return {(seg, lane): out for (seg, lane, _), out in zip(jobs, outs)}
 
 
 def compare(driver, calls, refs, limits: dict) -> dict:
     """Every call's every lane against the reference: the widest counter
-    gap (share of a lane's requests) and latency gap (relative)."""
-    from bench.reference import gaps
+    gap (share of a lane's requests) and latency gap (relative), as the
+    driver's reference's ``gaps`` gives them."""
+    from bench.cell import load_reference, reference_source
+    gaps = load_reference(reference_source(driver.reference)).gaps
     worst = {"counter_gap": 0.0, "latency_gap": 0.0}
     failed = 0
     for k, ans in calls:

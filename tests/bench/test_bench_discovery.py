@@ -5,6 +5,8 @@ import re
 import pytest
 
 from bench import cell as C
+from bench import drivers as D
+from bench import generate as G
 
 SPEC = C.load_spec()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -59,6 +61,50 @@ def test_cell_finds_its_files_by_name(workload):
     assert entry["chips"] in (1, 4) and len(entry["why"]) <= 200
 
 
+def _driver(workload):
+    cell = C.load_cell(workload)
+    return D.build(cell.config, cell.traffic, 1)
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_cell_resolves_generator_driver_and_reference(workload):
+    """Each cell's generator, driver and reference are a built-in or a file
+    of their directory, and the reference has what a run calls."""
+    cell = C.load_cell(workload)
+    gen = cell.config["generator"]
+    assert gen in G.GENERATORS or C.plugin_path("generator", gen).is_file()
+    kind = cell.traffic["driver"]
+    assert kind in D.DRIVERS or C.plugin_path("driver", kind).is_file()
+    drv = _driver(workload)
+    assert isinstance(drv, D.Segments) and drv.fields
+    ref = C.load_reference(C.reference_source(drv.reference))
+    assert callable(ref.run_job) and callable(ref.gaps)
+
+
+REF_ALONE = """
+import sys
+sys.path.insert(0, {root!r})
+from bench.cell import load_reference
+load_reference({source!r})
+print(sorted(m for m in sys.modules
+             if m.split(".")[0] in ("repro", "jax", "jaxlib")))
+"""
+
+
+@pytest.mark.parametrize("name", sorted({_driver(w).reference
+                                         for w in WORKLOADS}))
+def test_reference_imports_nothing_of_the_program(name):
+    """Loaded alone in a fresh process, a reference brings in nothing of the
+    program under test, nor JAX."""
+    import subprocess
+    import sys
+    code = REF_ALONE.format(root=str(C.ROOT),
+                            source=C.reference_source(name))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, cwd=C.ROOT, check=True)
+    assert out.stdout.strip() == "[]"
+
+
 @pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda c: c["name"])
 def test_configurations(entry):
     cfg = json.load(open(C.ROOT / entry["file"]))
@@ -77,9 +123,10 @@ def test_four_chip_cells_are_at_most_half():
     assert len(four) <= max(1, len(SPEC["workloads"]) // 2)
 
 
-def test_a_new_cell_needs_only_new_entries():
+def test_a_new_cell_needs_only_new_entries(tmp_path, monkeypatch):
     """A cell is found from names alone: an added workload entry that pairs
-    existing files needs no code."""
+    existing files needs no code, and a new configuration brings its
+    generator as a file of ``bench/generators``."""
     spec = json.loads(json.dumps(SPEC))
     spec["workloads"].append(dict(
         name="fig2_roster_again", config="paper_fig2",
@@ -90,6 +137,23 @@ def test_a_new_cell_needs_only_new_entries():
     assert [m["name"] for m in cell.per_layer] == ["ingest_s", "warmup_s"]
     with pytest.raises(KeyError):
         C.load_cell("no_such_cell")
+
+    gen_dir = tmp_path / "generators"
+    gen_dir.mkdir()
+    (gen_dir / "ones.py").write_text(
+        "import numpy as np\n\n\ndef requests(cfg, traffic, seed):\n"
+        "    return {'objs': np.full(cfg['n_requests'], seed)}\n")
+    monkeypatch.setitem(C.PLUGIN_DIRS, "generator", gen_dir)
+    (tmp_path / "ones.json").write_text(json.dumps(
+        dict(name="ones", generator="ones", n_requests=4)))
+    spec["configs"].append(dict(name="ones", file=str(tmp_path / "ones.json"),
+                                reduced=[], source="a test", why="a test"))
+    spec["workloads"].append(dict(
+        name="ones_replay", config="ones", traffic="replay_poisson",
+        chips=1, why="a new configuration with its own generator"))
+    cell = C.load_cell("ones_replay", spec)
+    assert cell.config["generator"] not in G.GENERATORS
+    assert G.requests(cell.config, cell.traffic, 7)["objs"].tolist() == [7] * 4
 
 
 def test_missing_reader_is_an_error():

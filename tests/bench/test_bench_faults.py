@@ -39,6 +39,8 @@ SMALL = {
         dict(segment_requests=4096, chunk_size=2048, use_kernel="interpret")),
     "fig2_roster_pareto": (dict(n_requests=6000),
                            dict(segment_requests=2000)),
+    "fig2_replay_lru": (dict(n_requests=8192),
+                        dict(segment_requests=4096, chunk_size=2048)),
     "fig2_grid_fabric4": (dict(n_requests=6000),
                           dict(segment_requests=2000, devices=1)),
 }
@@ -111,7 +113,10 @@ FAULTS = {"unchanged_state": _unchanged_state, "half_batch": _half_batch,
           "answer_altered": _answer_altered}
 
 
-@pytest.mark.parametrize("name", ["fig2_replay_stoch", "fig2_roster_pareto"])
+CELLS = ["fig2_replay_stoch", "fig2_roster_pareto", "fig2_replay_lru"]
+
+
+@pytest.mark.parametrize("name", CELLS)
 def test_sound_run_is_correct(name, fresh_jit):
     out = run_small(small_cell(name))
     assert out["correct"], out["checks"]
@@ -120,7 +125,7 @@ def test_sound_run_is_correct(name, fresh_jit):
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-@pytest.mark.parametrize("name", ["fig2_replay_stoch", "fig2_roster_pareto"])
+@pytest.mark.parametrize("name", CELLS)
 def test_fault_is_not_correct(name, fault, monkeypatch, fresh_jit):
     FAULTS[fault](monkeypatch)
     out = run_small(small_cell(name))
