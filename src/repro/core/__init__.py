@@ -21,8 +21,8 @@ from .hierarchy import (HierResult, HierTrace, make_hier_trace,
 from .percentile import QuantileSummary, StreamingQuantile
 from .ranking import (BASELINES, OURS, POLICIES, Policy, PolicyParams,
                       Substrate, make_substrate)
-from .simulator import (SimResult, latency_improvement, simulate,
-                        simulate_chunked, simulate_stream)
+from .simulator import (SimResult, SlotResult, latency_improvement,
+                        simulate, simulate_chunked, simulate_stream)
 from .sweep import HierSweepGrid, SweepGrid, sweep_grid, sweep_hier_grid
 from .trace import (RequestStream, Trace, make_trace, stream_of_trace,
                     trace_of_stream)
@@ -37,8 +37,8 @@ __all__ = [
     "Substrate", "make_substrate",
     "HierResult", "HierTrace", "make_hier_trace", "simulate_hier",
     "simulate_hier_chunked",
-    "SimResult", "latency_improvement", "simulate", "simulate_chunked",
-    "simulate_stream",
+    "SimResult", "SlotResult", "latency_improvement", "simulate",
+    "simulate_chunked", "simulate_stream",
     "HierSweepGrid", "SweepGrid", "sweep_grid", "sweep_hier_grid",
     "RequestStream", "Trace", "make_trace", "stream_of_trace",
     "trace_of_stream",

@@ -696,6 +696,28 @@ def _result_of_state(state: SimState) -> SimResult:
                      state.n_misses, state.n_evictions)
 
 
+class SlotResult(NamedTuple):
+    """A slot-table replay's :class:`SimResult` fields, then the table's
+    own counters (:class:`repro.core.state.SlotView`)."""
+
+    total_latency: jax.Array
+    n_hits: jax.Array
+    n_delayed: jax.Array
+    n_misses: jax.Array
+    n_evictions: jax.Array
+    n_inserts: jax.Array
+    n_reclaims: jax.Array
+
+    n_requests = SimResult.n_requests
+    mean_latency = SimResult.mean_latency
+    hit_ratio = SimResult.hit_ratio
+
+
+def _slot_result_of_state(state: SlotState) -> SlotResult:
+    return SlotResult(*_result_of_state(state.sim), state.tab.n_inserts,
+                      state.tab.n_reclaims)
+
+
 # ---------------------------------------------------------------------------
 # Sparse slot-table engine (DESIGN.md §14): the dense commit/serve machinery
 # runs unchanged over an [S]-shaped slot axis; a hashed open-addressing
@@ -706,6 +728,7 @@ def _result_of_state(state: SimState) -> SimResult:
 # order-independent (min) or id-tiebroken (_argmin_id), so the hash seed
 # and slot layout cannot leak into results (tests/test_slots.py).
 # ---------------------------------------------------------------------------
+@scope("slot_lookup")
 def _slot_lookup_insert(state: SlotState, obj, size, zp, valid):
     """Resolve ``obj`` to its slot, inserting on first touch.
 
@@ -715,8 +738,10 @@ def _slot_lookup_insert(state: SlotState, obj, size, zp, valid):
     non-in-flight slot in probe order is reclaimed instead — its occupant
     is evicted if cached and its statistics reset to first-touch values (a
     documented approximation that never fires when the table is sized to
-    the universe, :func:`repro.core.state.slot_table_size`).  ``valid``
-    gates insertion on padded streaming steps (python True constant-folds).
+    the universe, :func:`repro.core.state.slot_table_size`).  Every insert
+    counts in the table's ``n_inserts``, a reclaim also in ``n_reclaims``.
+    ``valid`` gates insertion on padded streaming steps (python True
+    constant-folds).
     """
     tab = state.tab
     slot, found, has_space = slot_probe(tab.key_tab, obj, tab.seed)
@@ -737,9 +762,10 @@ def _slot_lookup_insert(state: SlotState, obj, size, zp, valid):
             dist = (jnp.arange(n, dtype=jnp.int32) - h) % n
             cand = jnp.where(sim.obj.in_flight, jnp.int32(n), dist)
             d = jnp.min(cand)
-            return (h + jnp.where(d < n, d, 0)) % n
+            return (h + jnp.where(d < n, d, 0)) % n, jnp.int32(1)
 
-        v = jax.lax.cond(has_space, lambda: slot, reclaimed)
+        v, reclaim = jax.lax.cond(has_space, lambda: (slot, jnp.int32(0)),
+                                  reclaimed)
         o = sim.obj
         was_cached = o.cached[v]
         was_inflight = o.in_flight[v]
@@ -768,7 +794,9 @@ def _slot_lookup_insert(state: SlotState, obj, size, zp, valid):
             lambda: jnp.min(jnp.where(o.in_flight, o.complete_t, jnp.inf)),
             lambda: sim.min_complete)
         tb = tb._replace(key_tab=tb.key_tab.at[v].set(obj),
-                         sizes=tb.sizes.at[v].set(size))
+                         sizes=tb.sizes.at[v].set(size),
+                         n_inserts=tb.n_inserts + 1,
+                         n_reclaims=tb.n_reclaims + reclaim)
         return SlotState(sim=sim._replace(obj=o, free=free, n_evictions=nev,
                                           min_complete=min_c), tab=tb), v
 
@@ -811,44 +839,6 @@ def _slot_chunk_step_jit(state: SlotState, times, objs, z_draw, valid, delta,
         else (times, objs, z_draw, valid)
     state, _ = jax.lax.scan(step, state, chunk)
     return state
-
-
-def _simulate_stream_slots(stream: RequestStream, capacity, policy: str,
-                           params: PolicyParams, key, estimate_z: bool,
-                           score_mode: str, chunk_size: int, rebase: bool,
-                           n_slots, slot_seed: int,
-                           prefetch: bool) -> SimResult:
-    """Slot-mode body of :func:`simulate_stream` (the ``state_mode='slots'``
-    route).  Device residency is O(n_slots + n_universe + chunk_size) — the
-    14-field per-object state is [S]-shaped, so million-object universes
-    cost two [N] arrays (sizes, z-priors) plus a table sized to the
-    *touched* key set, not the key space."""
-    times64 = np.asarray(stream.times, np.float64)
-    objs = np.asarray(stream.objs, np.int32)
-    z_draw = np.asarray(stream.z_draw, np.float32)
-    sizes_full = jnp.asarray(stream.sizes, jnp.float32)
-    z_prior_full = jnp.asarray(stream.z_mean, jnp.float32)
-    if n_slots is None:
-        n_slots = slot_table_size(int(np.unique(objs).size))
-    state = init_slot_state(int(n_slots), jnp.float32(capacity),
-                            jnp.asarray(key).copy(), slot_seed)
-
-    def dispatch(state, chunk):
-        t, i, z, valid, delta = chunk
-        return _slot_chunk_step_jit(state, t, i, z, valid, delta, sizes_full,
-                                    z_prior_full, params, policy, estimate_z,
-                                    score_mode)
-
-    chunks = _stream_chunks(times64, objs, z_draw, chunk_size, rebase)
-    if prefetch:
-        pending = next(chunks, None)
-        while pending is not None:
-            cur, pending = pending, next(chunks, None)
-            state = dispatch(state, cur)
-    else:
-        for cur in chunks:
-            state = dispatch(state, cur)
-    return _result_of_state(state.sim)
 
 
 def _stream_chunks(times64, objs, z_draw, chunk_size: int, rebase: bool):
@@ -903,7 +893,7 @@ def simulate_stream(stream: RequestStream, capacity: float,
                     prefetch: bool = True,
                     state_mode: str = "dense",
                     n_slots: int | None = None,
-                    slot_seed: int = 0) -> SimResult:
+                    slot_seed: int = 0) -> SimResult | SlotResult:
     """Run one policy over a host-resident stream, one chunk at a time.
 
     Device residency is O(n_objects + chunk_size) regardless of trace
@@ -940,7 +930,10 @@ def simulate_stream(stream: RequestStream, capacity: float,
     dense ``[N]`` struct, so million-object universes replay at bounded
     RSS.  Results are bitwise identical to dense mode whenever the table
     never fills (tests/test_slots.py); ``slot_seed`` picks the hash seed
-    and is bitwise invisible in results.
+    and is bitwise invisible in results.  Its result is a
+    :class:`SlotResult`: the same fields, then the table's ``n_inserts``
+    and ``n_reclaims``.  Both modes run the same host loop under the same
+    ``repro.stream.*`` spans.
     """
     if params is None:
         params = PolicyParams()
@@ -951,37 +944,51 @@ def simulate_stream(stream: RequestStream, capacity: float,
     if state_mode not in ("dense", "slots"):
         raise ValueError(f"state_mode={state_mode!r}; expected 'dense' or "
                          f"'slots'")
-    if state_mode == "slots":
-        if evict_top not in (None, 0):
-            raise ValueError(
-                f"evict_top={evict_top} is not supported with "
-                f"state_mode='slots' — the precomputed victim order "
-                f"tie-breaks by slot index, which cannot reproduce dense "
-                f"object-id order; the slot engine pins evict_top=0 (the "
-                f"id-tiebroken argmin path, bitwise identical in dense "
-                f"results)")
-        return _simulate_stream_slots(stream, capacity, policy, params, key,
-                                      estimate_z, score_mode, chunk_size,
-                                      rebase, n_slots, slot_seed, prefetch)
+    if state_mode == "slots" and evict_top not in (None, 0):
+        raise ValueError(
+            f"evict_top={evict_top} is not supported with "
+            f"state_mode='slots' — the precomputed victim order "
+            f"tie-breaks by slot index, which cannot reproduce dense "
+            f"object-id order; the slot engine pins evict_top=0 (the "
+            f"id-tiebroken argmin path, bitwise identical in dense "
+            f"results)")
+    if state_mode == "dense" and n_slots is not None:
+        raise ValueError("n_slots applies only with state_mode='slots'")
     with span("stream.init"):
-        if n_slots is not None:
-            raise ValueError("n_slots applies only with state_mode='slots'")
         times64 = np.asarray(stream.times, np.float64)
         objs = np.asarray(stream.objs, np.int32)
         z_draw = np.asarray(stream.z_draw, np.float32)
-        sizes = jnp.asarray(stream.sizes, jnp.float32)
         # state.key is donated with the rest of the carry — keep the
         # caller's key array alive by seeding the state with a copy.
-        state = init_state(stream.n_objects, jnp.float32(capacity),
-                           jnp.asarray(key).copy(),
-                           jnp.asarray(stream.z_mean, jnp.float32))
+        if state_mode == "slots":
+            # the slot engine's only [N_universe] device arrays
+            sizes_full = jnp.asarray(stream.sizes, jnp.float32)
+            z_prior_full = jnp.asarray(stream.z_mean, jnp.float32)
+            if n_slots is None:
+                n_slots = slot_table_size(int(np.unique(objs).size))
+            state = init_slot_state(int(n_slots), jnp.float32(capacity),
+                                    jnp.asarray(key).copy(), slot_seed)
+
+            def run_chunk(state, t, i, z, valid, delta):
+                return _slot_chunk_step_jit(
+                    state, t, i, z, valid, delta, sizes_full, z_prior_full,
+                    params, policy, estimate_z, score_mode)
+            result_of = _slot_result_of_state
+        else:
+            sizes = jnp.asarray(stream.sizes, jnp.float32)
+            state = init_state(stream.n_objects, jnp.float32(capacity),
+                               jnp.asarray(key).copy(),
+                               jnp.asarray(stream.z_mean, jnp.float32))
+
+            def run_chunk(state, t, i, z, valid, delta):
+                return _chunk_step_jit(state, t, i, z, valid, delta, sizes,
+                                       params, policy, estimate_z,
+                                       score_mode, evict_top)
+            result_of = _result_of_state
 
     def dispatch(state, chunk):
-        t, i, z, valid, delta = chunk
         with span("stream.dispatch"):
-            return _chunk_step_jit(state, t, i, z, valid, delta, sizes,
-                                   params, policy, estimate_z, score_mode,
-                                   evict_top)
+            return run_chunk(state, *chunk)
 
     # the builder yields exactly n_chunks chunks; pulling no further keeps
     # every prep span around real work (a suspended generator holds none)
@@ -1006,7 +1013,7 @@ def simulate_stream(stream: RequestStream, capacity: float,
     else:
         for _ in range(n_chunks):
             state = dispatch(state, prep())
-    return _result_of_state(state)
+    return result_of(state)
 
 
 def simulate_chunked(trace: Trace, capacity: float,

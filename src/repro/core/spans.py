@@ -17,7 +17,7 @@ import jax
 PREFIX = "repro."
 SPANS = ("stream.init", "stream.prep", "stream.dispatch",
          "sweep.prologue", "sweep.dispatch")
-SCOPES = ("serve", "commit", "rank_select", "evict")
+SCOPES = ("serve", "commit", "rank_select", "evict", "slot_lookup")
 
 
 def span(name: str) -> jax.profiler.TraceAnnotation:

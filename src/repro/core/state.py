@@ -137,11 +137,16 @@ class SlotView(NamedTuple):
                       reduction the simulator runs over the slot axis is
                       either order-independent or id-tiebroken —
                       :func:`repro.kernels.ref.tiebreak_argmin_ref`)
+    n_inserts  i32  — keys given a slot on first touch (reclaims included)
+    n_reclaims i32  — inserts that found the table full and took an
+                      occupied slot (0 whenever the table holds every key)
     """
 
     key_tab: jax.Array
     sizes: jax.Array
     seed: jax.Array
+    n_inserts: jax.Array
+    n_reclaims: jax.Array
 
 
 class SlotState(NamedTuple):
@@ -209,7 +214,8 @@ def init_slot_state(n_slots: int, capacity, key: jax.Array,
     tab = SlotView(
         key_tab=jnp.full((n_slots,), SLOT_EMPTY, jnp.int32),
         sizes=jnp.zeros((n_slots,), jnp.float32),
-        seed=jnp.uint32(seed))
+        seed=jnp.uint32(seed),
+        n_inserts=jnp.int32(0), n_reclaims=jnp.int32(0))
     return SlotState(sim=sim, tab=tab)
 
 

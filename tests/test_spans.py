@@ -14,9 +14,9 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.core import PolicyParams, simulate_stream, sweep_grid
-from repro.core.simulator import _chunk_step_jit
+from repro.core.simulator import _chunk_step_jit, _slot_chunk_step_jit
 from repro.core.spans import PREFIX, SCOPES, SPANS, scope, span
-from repro.core.state import init_state
+from repro.core.state import init_slot_state, init_state
 from repro.core.trace import stream_of_trace
 from repro.data.traces import SyntheticSpec, synthetic_trace
 
@@ -103,7 +103,9 @@ def test_names_come_from_the_tuples():
 
 def test_scan_body_scopes_reach_the_compiled_program():
     """Every device scope names ops of the chunk-step program the replay
-    runs (the rank-select branch and both evict loops included)."""
+    runs (the rank-select branch and both evict loops included): the dense
+    program carries every scope but the slot table's lookup, the slot
+    program every scope."""
     trace = _trace(n_requests=64)
     state = init_state(trace.n_objects, jax.numpy.float32(60.0),
                        jax.random.key(0), trace.z_mean)
@@ -112,6 +114,14 @@ def test_scan_body_scopes_reach_the_compiled_program():
         jax.numpy.float32(0.0), trace.sizes, PolicyParams(),
         policy_name="stoch_vacdh", estimate_z=True, score_mode="rank",
         evict_top=4).compile()
-    names = set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
-    for s in SCOPES:
-        assert any(f"/{PREFIX}{s}/" in n for n in names), s
+    slots = init_slot_state(64, jax.numpy.float32(60.0), jax.random.key(0))
+    compiled_slots = _slot_chunk_step_jit.lower(
+        slots, trace.times, trace.objs.astype("int32"), trace.z_draw, None,
+        jax.numpy.float32(0.0), trace.sizes, trace.z_mean, PolicyParams(),
+        policy_name="stoch_vacdh", estimate_z=True,
+        score_mode="rank").compile()
+    for program, scopes in ((compiled, set(SCOPES) - {"slot_lookup"}),
+                            (compiled_slots, SCOPES)):
+        names = set(re.findall(r'op_name="([^"]*)"', program.as_text()))
+        for s in scopes:
+            assert any(f"/{PREFIX}{s}/" in n for n in names), s

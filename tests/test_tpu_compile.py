@@ -4,8 +4,8 @@ Interpret-mode parity (tests/test_kernels.py) cannot see what the TPU
 compiler refuses: block shapes outside the 8x128 tiling rule, scalar
 stores into vector memory, kernels that do not fit fast memory.  These
 tests lower and compile the hot path's Pallas kernels, and the streamed
-replay's chunk step with the eviction kernel inside its commit loop, for
-one chip of a described ``v5e:2x2`` topology at real sizes.  Nothing runs;
+replay's chunk steps (dense, and the slot table's) with the scoring kernel
+inside their commit loops, for one chip of a described ``v5e:2x2`` topology at real sizes.  Nothing runs;
 a compile that passes says nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
@@ -22,8 +22,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import PolicyParams
-from repro.core.simulator import _chunk_step_jit
-from repro.core.state import init_state
+from repro.core.simulator import _chunk_step_jit, _slot_chunk_step_jit
+from repro.core.state import init_slot_state, init_state
 from repro.kernels.lane_scatter import lane_scatter_add, lane_scatter_set
 from repro.kernels.ranking_score import ranking_scores, ranking_victim_order
 
@@ -103,3 +103,24 @@ def test_stream_chunk_step_with_kernel_compiles(one_chip):
         state, f32(chunk), _spec(one_chip, (chunk,), jnp.int32), f32(chunk),
         f32(), f32(n), params)
     assert compiled.memory_analysis() is not None
+
+
+def test_slot_chunk_step_with_kernel_compiles(one_chip):
+    """The exact CDN replay's chunk step: 25000 requests over a 131072-slot
+    table and a 200000-key universe, the table's probe and insert, and
+    eq.-16 scoring through the compiled ``ranking_scores`` kernel."""
+    slots, chunk, universe = 131_072, 25_000, 200_000
+    spec = lambda s: _spec(one_chip, s.shape, s.dtype)
+    state = jax.tree.map(spec, jax.eval_shape(
+        lambda: init_slot_state(slots, jnp.float32(939.5),
+                                jax.random.key(0))))
+    params = jax.tree.map(lambda x: _spec(one_chip, jnp.shape(x),
+                                          jnp.result_type(x)),
+                          PolicyParams(omega=1.0))
+    f32 = lambda *s: _spec(one_chip, s, jnp.float32)
+    compiled = _compile(
+        lambda st, t, o, z, d, sz, zp, p: _slot_chunk_step_jit(
+            st, t, o, z, None, d, sz, zp, p, "stoch_vacdh", True, "kernel"),
+        state, f32(chunk), _spec(one_chip, (chunk,), jnp.int32), f32(chunk),
+        f32(), f32(universe), f32(universe), params)
+    assert "ranking_scores" in compiled.as_text()
