@@ -10,7 +10,8 @@ read instead of the ~7 kernel launches the unfused jnp version costs.
 TPU layout: the 1-D ``(N,)`` streams are padded and viewed as ``(rows,
 128)`` lane tiles; a block is ``block // 128`` rows (a multiple of 8 rows,
 i.e. ``block`` a multiple of 1024, unless one block covers the whole
-table).  ``omega`` rides in as a broadcast ``(1, 128)`` tile, and each
+table); by default it is sized from the table (``_auto_block``).
+``omega`` rides in as a broadcast ``(1, 128)`` tile, and each
 block's candidates are written into one lane-dense ``(8k, 128)`` tile
 (candidate ``e`` at flat position ``e``) — every block shape the compiler
 sees obeys the 8x128 rule, and no value is read or stored at a dynamic
@@ -29,6 +30,16 @@ from jax.experimental import pallas as pl
 INF = 3.4e38  # python float: jnp constants would be captured by the kernel
 _LANES = 128
 _SUBLANES = 8
+_MIN_BLOCK = _SUBLANES * _LANES     # the smallest block obeying 8x128
+_BLOCK_CAP = 128 * _LANES           # 16384 slots, ~393 KB moved per step
+
+
+def _auto_block(n: int) -> int:
+    """Slots per grid step for an ``(N,)`` table: the whole table in one
+    block up to ``_MIN_BLOCK`` slots, else the largest multiple of
+    ``_MIN_BLOCK`` up to ``_BLOCK_CAP`` that the table, padded to whole
+    ``_MIN_BLOCK`` blocks, holds (8 steps at 131 072 slots)."""
+    return min(_BLOCK_CAP, -(-n // _MIN_BLOCK) * _MIN_BLOCK)
 
 
 def _rank_select_kernel(om_ref, lam_ref, z_ref, r_ref, s_ref, c_ref, f_ref,
@@ -67,11 +78,19 @@ def _rank_select_kernel(om_ref, lam_ref, z_ref, r_ref, s_ref, c_ref, f_ref,
     bidx_ref[...] = idxs
 
 
-def _rank_select(lam, z, resid, sizes, cached, omega, top: int, block: int,
-                 interpret: bool):
+def _rank_select(lam, z, resid, sizes, cached, omega, top: int,
+                 block: int | None, interpret: bool):
     """Run the fused pass; returns ``(scores (N,), cand_vals [G, top],
-    cand_idx [G, top])`` with each block's candidates in extraction order."""
+    cand_idx [G, top])`` with each block's candidates in extraction order.
+
+    ``block`` is the slots per grid step; ``None`` sizes it from the table
+    (``_auto_block``): a step costs a fixed ~0.4 us on a v5e beside its
+    bytes, so 1024-slot blocks left a 131 072-slot table bound by its 128
+    steps.  Scores are elementwise and the merge keeps the first minimum,
+    so the results do not depend on the block."""
     n = lam.shape[0]
+    if block is None:
+        block = _auto_block(n)
     if block % _LANES:
         raise ValueError(f"block={block} must be a multiple of {_LANES}")
     rows = -(-n // _LANES)
@@ -111,12 +130,13 @@ def _rank_select(lam, z, resid, sizes, cached, omega, top: int, block: int,
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def ranking_scores(lam, z, resid, sizes, cached, *, omega=1.0,
-                   block: int = 1024, interpret: bool = False):
+                   block: int | None = None, interpret: bool = False):
     """All inputs (N,); returns (scores (N,), victim_idx, victim_score).
 
     ``omega`` is a scalar *operand* (python float or traced f32) so the
     simulator can thread a swept PolicyParams.omega through without
-    retracing.  ``interpret=True`` runs the Pallas interpreter (any
+    retracing.  ``block`` (slots per grid step) defaults to one sized from
+    the table.  ``interpret=True`` runs the Pallas interpreter (any
     backend); the default compiles for the TPU.
     """
     f, bvals, bidx = _rank_select(lam, z, resid, sizes, cached, omega, 1,
@@ -127,7 +147,7 @@ def ranking_scores(lam, z, resid, sizes, cached, *, omega=1.0,
 
 @functools.partial(jax.jit, static_argnames=("top", "block", "interpret"))
 def ranking_victim_order(lam, z, resid, sizes, cached, *, omega=1.0,
-                         top: int = 8, block: int = 1024,
+                         top: int = 8, block: int | None = None,
                          interpret: bool = False):
     """Fused rank-and-select: eq. 16 scores AND the masked top-``top``
     ascending victim order in one streaming pass (DESIGN.md §10).

@@ -194,6 +194,59 @@ def test_ranking_victim_order_sparse_cache_emits_inf_sentinels():
     assert np.isinf(v[2:]).all()        # no finite duplicates past the cache
 
 
+def _rank_inputs(n, seed):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    return (jax.random.uniform(ks[0], (n,), minval=1e-3, maxval=50.0),
+            jax.random.uniform(ks[1], (n,), minval=1e-3, maxval=2.0),
+            jax.random.uniform(ks[2], (n,), minval=1e-3, maxval=10.0),
+            jax.random.uniform(ks[3], (n,), minval=1.0, maxval=100.0),
+            jax.random.bernoulli(ks[4], 0.5, (n,)))
+
+
+def _rank_both(n, block):
+    """``ranking_scores`` and ``ranking_victim_order(top=8)`` over one
+    table, interpreted, as numpy arrays."""
+    from repro.kernels.ranking_score import ranking_victim_order
+    args = _rank_inputs(n, 11)
+    f, idx, val = ranking_scores(*args, omega=1.0, block=block,
+                                 interpret=True)
+    g, order, vals = ranking_victim_order(*args, omega=1.0, top=8,
+                                          block=block, interpret=True)
+    return [np.asarray(x) for x in (f, idx, val, g, order, vals)]
+
+
+@pytest.mark.parametrize("block", [1024, 4096, 8192, 16384, None])
+@pytest.mark.parametrize("n", [100, 5000, 40_000])
+def test_rank_select_is_block_invariant(n, block):
+    """Scores, the victim and the top-8 order are bitwise the same at any
+    block, the table-sized default included: scores are elementwise and
+    the merge keeps the first minimum across blocks."""
+    for got, want in zip(_rank_both(n, block), _rank_both(n, 1024)):
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("n", [1, 100, 1024, 1025, 5000, 40_000, 131_072,
+                               200_000])
+def test_auto_block_sizes_the_grid_from_the_table(n):
+    """One block up to 1024 slots; above, a multiple of 1024 up to the
+    16384-slot cap and within the padded table; 8 steps at 131 072."""
+    from repro.kernels.ranking_score import _auto_block, _rank_select
+    block = _auto_block(n)
+    spec = jax.ShapeDtypeStruct((n,), jnp.float32)
+    cand = jax.eval_shape(
+        lambda *a: _rank_select(*a, 1.0, 1, None, True)[1],
+        spec, spec, spec, spec, jax.ShapeDtypeStruct((n,), jnp.bool_))
+    steps = cand.shape[0]
+    if n <= 1024:
+        assert steps == 1
+    else:
+        assert block % 1024 == 0 and block <= 16384
+        assert block <= -(-n // 1024) * 1024
+        assert steps == -(-n // block)
+    if n == 131_072:
+        assert steps == 8
+
+
 def test_victim_order_ref_is_argmin_remove_sequence():
     """victim_order_ref == iterative masked argmin-and-remove, ties and
     non-cached +inf sentinels included (the eviction-loop contract)."""

@@ -15,6 +15,7 @@ compile cache is off around these compiles (an entry written for a chip
 cannot be read back without one).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -67,11 +68,17 @@ def test_ranking_victim_order_compiles(one_chip, n):
              *streams, cached)
 
 
-def test_ranking_scores_compiles(one_chip):
-    n = 4096
+@pytest.mark.parametrize("n,steps", [(4096, 1), (131_072, 8)])
+def test_ranking_scores_compiles(one_chip, n, steps):
+    """At the default block the custom call writes one (8, 128) candidate
+    tile a grid step: 8 steps over the slot engine's 131 072 slots."""
     streams = [_spec(one_chip, (n,), jnp.float32)] * 4
     cached = _spec(one_chip, (n,), jnp.bool_)
-    _compile(lambda *a: ranking_scores(*a, omega=1.0), *streams, cached)
+    compiled = _compile(lambda *a: ranking_scores(*a, omega=1.0),
+                        *streams, cached)
+    call = re.search(r"= \(f32\[\d+,128\][^ ]* f32\[(\d+),128\].*"
+                     r"custom-call", compiled.as_text())
+    assert call and int(call.group(1)) == 8 * steps
 
 
 @pytest.mark.parametrize("scatter", [lane_scatter_set, lane_scatter_add],
