@@ -1,66 +1,25 @@
-"""Memory probes, two kinds, both subprocess-isolated (``--only memory``):
-
-1. **HLO forensics** (default): compile a layer-reduced dry-run cell and
-   dump the largest HLO buffers (by result shape) + temp scaling vs
-   n_layers.  Needs a 512-device host platform, so ``XLA_FLAGS`` must be
-   set BEFORE jax initializes — :func:`main` sets it, and
-   ``benchmarks/run.py`` therefore invokes this probe as a *subprocess*:
-   importing it into an already-initialized jax process would either
-   clobber the caller's backend or find too few devices.
-
-2. **SimState RSS scaling** (``--simstate``): sparse slot-table vs dense
-   streamed-replay peak RSS at nominal universe sizes N in {1e4, 1e5,
-   1e6} (DESIGN.md §14).  ``ru_maxrss`` is a *process-lifetime* high-water
-   mark, so each (N, mode) cell runs in its own child process
-   (``--simstate-child``) — measuring dense then slots in one process
-   would report dense's peak for both.  The dense engine holds 14 O(N)
-   state columns and scores an O(N) eviction substrate per commit; the
-   slot engine's table is sized by *distinct-touched* keys, so its RSS is
-   bounded by the request budget, not the nominal universe.
+"""SimState RSS scaling probe (``--only memory``): sparse slot-table vs
+dense streamed-replay peak RSS at nominal universe sizes N in {1e4, 1e5,
+1e6} (DESIGN.md §14).  ``ru_maxrss`` is a *process-lifetime* high-water
+mark, so each (N, mode) cell runs in its own child process
+(``--simstate-child``) — measuring dense then slots in one process would
+report dense's peak for both.  The dense engine holds 14 O(N) state
+columns and scores an O(N) eviction substrate per commit; the slot
+engine's table is sized by *distinct-touched* keys, so its RSS is bounded
+by the request budget, not the nominal universe.
 
 Importing this module is side-effect free."""
 import argparse
-import dataclasses
 import json
 import os
-import re
 import subprocess
 import sys
-from collections import Counter
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 REPO_ROOT = os.path.join(os.path.dirname(__file__), "..")
 SIMSTATE_SIZES = (10_000, 100_000, 1_000_000)
 SIMSTATE_REQUESTS = 60_000      # bounded: RSS is the headline, not req/s
-
-_SHAPE = re.compile(r"= (\w+)\[([0-9,]+)\]")
-_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
-          "u32": 4, "f32": 4, "s64": 8, "f64": 8}
-
-
-def top_buffers(hlo, n=25):
-    sizes = Counter()
-    for m in _SHAPE.finditer(hlo):
-        dt, dims = m.groups()
-        el = 1
-        for d in dims.split(","):
-            el *= int(d)
-        b = el * _BYTES.get(dt, 4)
-        if b > 64 * 2**20:
-            sizes[f"{dt}[{dims}]"] += 1
-    items = sorted(sizes.items(),
-                   key=lambda kv: -_size_of(kv[0]))[:n]
-    return [(k, c, _size_of(k) / 2**30) for k, c in items]
-
-
-def _size_of(s):
-    dt, dims = re.match(r"(\w+)\[([0-9,]+)\]", s).groups()
-    el = 1
-    for d in dims.split(","):
-        el *= int(d)
-    return el * _BYTES.get(dt, 4)
-
 
 def _simstate_stream(n_keys: int, n_requests: int, seed: int = 0):
     """Zipf(0.9)-over-the-nominal-universe request stream, pure numpy.
@@ -175,68 +134,19 @@ def run_simstate_probe(sizes=SIMSTATE_SIZES, n_requests=SIMSTATE_REQUESTS,
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="grok-1-314b")
-    ap.add_argument("--shape", default="train_4k")
-    ap.add_argument("--mesh", default="single")
-    ap.add_argument("--layers", type=int, nargs="+", default=[2, 4])
-    ap.add_argument("--simstate", action="store_true",
-                    help="run the SimState RSS scaling probe instead of "
-                         "the HLO forensics probe")
     ap.add_argument("--simstate-child", nargs=2, metavar=("N", "MODE"),
                     default=None, help=argparse.SUPPRESS)
     ap.add_argument("--requests", type=int, default=SIMSTATE_REQUESTS)
     ap.add_argument("--timeout", type=float, default=1800.0,
-                    help="per-cell wall-clock budget for --simstate")
+                    help="per-cell wall-clock budget")
     args = ap.parse_args(argv)
 
-    # the SimState probes want the normal single-device CPU platform, NOT
-    # the 512-device HLO-forensics platform — handle them before any
-    # XLA_FLAGS mutation
     if args.simstate_child is not None:
         n, mode = args.simstate_child
         row = simstate_child_row(int(n), mode, args.requests)
         print("SIMSTATE " + json.dumps(row), flush=True)
         return
-    if args.simstate:
-        run_simstate_probe(n_requests=args.requests,
-                           timeout_s=args.timeout)
-        return
-
-    # the probe is unusable without the 512-device host platform: keep any
-    # unrelated pre-existing XLA_FLAGS, but replace a conflicting
-    # device-count setting outright (a stale count would surface much
-    # later as a confusing mesh-shape error)
-    flag = "--xla_force_host_platform_device_count=512"
-    prior = os.environ.get("XLA_FLAGS", "")
-    kept = [f for f in prior.split()
-            if "xla_force_host_platform_device_count" not in f]
-    os.environ["XLA_FLAGS"] = " ".join(kept + [flag])
-    import jax
-
-    if jax.default_backend() != "cpu":
-        raise SystemExit(
-            f"the HLO forensics probe compiles for {flag.split('=')[1]} fake "
-            f"host devices, which exist on the CPU platform only; this "
-            f"process runs on {jax.default_backend()!r} (run it with "
-            f"JAX_PLATFORMS=cpu)")
-
-    from repro.configs import registry
-    from repro.launch.cells import input_specs
-    from repro.launch.mesh import make_production_mesh
-
-    mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
-    for L in args.layers:
-        cfg = dataclasses.replace(registry.get(args.arch), n_layers=L)
-        with mesh:
-            cell = input_specs(cfg, args.shape, mesh)
-            comp = jax.jit(cell.fn, donate_argnums=cell.donate).lower(
-                *cell.args).compile()
-        ma = comp.memory_analysis()
-        print(f"\n=== {args.arch} L={L} {args.shape}@{args.mesh}: "
-              f"temp={ma.temp_size_in_bytes/2**30:.2f}GiB "
-              f"args={ma.argument_size_in_bytes/2**30:.2f}GiB ===")
-        for shape_s, count, gib in top_buffers(comp.as_text()):
-            print(f"  {gib:8.2f} GiB x{count:<4d} {shape_s}")
+    run_simstate_probe(n_requests=args.requests, timeout_s=args.timeout)
 
 
 if __name__ == "__main__":

@@ -23,18 +23,13 @@ from pathlib import Path
 def _run_memory_probe() -> None:
     import subprocess
 
-    # two probes, both subprocess-isolated: the SimState RSS scaling rows
-    # (sparse slots vs dense at N in {1e4,1e5,1e6} — each cell is its own
-    # child so ru_maxrss is per-configuration) and the model-stack HLO
-    # forensics (must set XLA_FLAGS for 512 host devices before jax
-    # initializes, which cannot happen in this process)
-    for extra in (["--simstate"], ["--layers", "2"]):
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.probe_memory", *extra],
-            cwd=Path(__file__).parent.parent)
-        if proc.returncode != 0:
-            raise RuntimeError(f"probe_memory {extra[0]} exited "
-                               f"{proc.returncode}")
+    # the SimState RSS scaling rows (sparse slots vs dense at N in
+    # {1e4,1e5,1e6}), in a child that starts one grandchild per cell so
+    # ru_maxrss is per-configuration
+    proc = subprocess.run([sys.executable, "-m", "benchmarks.probe_memory"],
+                          cwd=Path(__file__).parent.parent)
+    if proc.returncode != 0:
+        raise RuntimeError(f"probe_memory exited {proc.returncode}")
 
 
 def main() -> int:
@@ -59,13 +54,12 @@ def main() -> int:
     from .common import emit
 
     jobs = [
-        # memory probes (probe_memory.py): SimState RSS scaling rows
-        # (slots vs dense) + model-stack HLO forensics, both as
-        # subprocesses (see _run_memory_probe).  First in line: on a chip
-        # host the children need the chip, which this process holds from
-        # its first jax computation on.  Opt-in only (--only memory): the
-        # cells compile and the dense million-object replay is out of the
-        # cache-benchmark jobs' wall-clock budget.
+        # memory probe (probe_memory.py): SimState RSS scaling rows
+        # (slots vs dense), as subprocesses (see _run_memory_probe).
+        # First in line: on a chip host the children need the chip, which
+        # this process holds from its first jax computation on.  Opt-in
+        # only (--only memory): the dense million-object replay is out of
+        # the cache-benchmark jobs' wall-clock budget.
         ("memory", _run_memory_probe),
         ("fig3", lambda: emit(fig3_trace_stats.run(), "fig3_trace_stats")),
         ("fig2", lambda: emit(fig2_synthetic.run(full=args.full),

@@ -1,42 +1,12 @@
-"""End-to-end serving driver: batched requests against a real (smoke-scale)
-model through the continuous batcher, plus a policy A/B on the delayed-hit
-prefix cache with stochastic prefill latency.
+"""Serving-engine policy A/B: a Zipf stream of prompt prefixes through
+``ServeEngine``'s delayed-hit prefix cache under four eviction policies, with
+stochastic prefill latency.
 
     PYTHONPATH=src python examples/serve_engine.py
 """
-import time
-
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import registry
-from repro.models import transformer as tf
 from repro.serving.engine import LatencyModel, ServeEngine
-from repro.serving.scheduler import ContinuousBatcher, Request, SchedulerConfig
-from repro.training.train_loop import make_serve_steps
-
-
-def real_model_demo():
-    cfg = registry.smoke("stablelm-1.6b")
-    params = tf.init_params(jax.random.key(0), cfg)
-    prefill, decode = make_serve_steps(cfg)
-    prefill_j = jax.jit(lambda c, b: prefill(params, c, b))
-    decode_j = jax.jit(lambda c, t, p: decode(params, c, tokens=t, pos0=p))
-    batcher = ContinuousBatcher(
-        SchedulerConfig(max_batch=4), prefill_step=prefill_j,
-        decode_step=decode_j,
-        init_cache=lambda b, cap: tf.init_cache(cfg, b, cap))
-    rng = np.random.default_rng(0)
-    t0 = time.time()
-    n = 8
-    for i in range(n):
-        toks = rng.integers(0, cfg.vocab, rng.integers(4, 12))
-        batcher.submit(Request(rid=i, tokens=toks, max_new=8))
-    done = batcher.drain()
-    dt = time.time() - t0
-    print(f"[real model] served {done} requests, {done * 8} tokens "
-          f"in {dt:.2f}s ({done * 8 / dt:.1f} tok/s on CPU smoke model)")
 
 
 def policy_ab_demo():
@@ -64,5 +34,4 @@ def policy_ab_demo():
 
 
 if __name__ == "__main__":
-    real_model_demo()
     policy_ab_demo()

@@ -1,72 +1,9 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
+"""Pure-jnp oracles for the ranking and lane-scatter kernels (the ground truth
+the tests compare against)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-NEG_INF = -1e30
-
-
-def flash_attention_ref(q, k, v, q_pos, k_pos, *, window: int = 0,
-                        softcap: float = 0.0, sink: int = 0) -> jax.Array:
-    """q (B,Sq,H,dh), k/v (B,Sk,KV,dh) -> (B,Sq,H,dh). f32 softmax."""
-    b, sq, h, dh = q.shape
-    kv = k.shape[2]
-    g = h // kv
-    qg = q.reshape(b, sq, kv, g, dh)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) * dh ** -0.5
-    if softcap > 0:
-        logits = softcap * jnp.tanh(logits / softcap)
-    keep = (k_pos[None, :] <= q_pos[:, None]) & (k_pos >= 0)[None, :]
-    if window > 0:
-        in_win = k_pos[None, :] > (q_pos[:, None] - window)
-        if sink > 0:
-            in_win |= (k_pos < sink)[None, :]
-        keep &= in_win
-    logits = jnp.where(keep[None, None, None], logits, NEG_INF)
-    w = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", w, v.astype(jnp.float32))
-    return out.reshape(b, sq, h, dh).astype(q.dtype)
-
-
-def decode_attention_ref(q, k, v, q_pos, k_pos, *, window: int = 0,
-                         softcap: float = 0.0, sink: int = 0) -> jax.Array:
-    """Single-token decode: q (B,1,H,dh) against k/v (B,Sk,KV,dh)."""
-    return flash_attention_ref(q, k, v, q_pos, k_pos, window=window,
-                               softcap=softcap, sink=sink)
-
-
-def gla_chunk_ref(q, k, v, log_f, log_i, *, normalize: bool = True):
-    """Sequential-recurrence oracle for chunked GLA.
-
-    q,k (B,S,H,dk), v (B,S,H,dv), gates (B,S,H) log-space.
-    Returns (y (B,S,H,dv), (S_state (B,H,dk,dv), n (B,H,dk)))."""
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
-    scale = dk ** -0.5
-
-    def step(carry, xs):
-        S, n = carry
-        qt, kt, vt, lf, li = xs
-        f = jnp.exp(lf)[..., None]                       # (B,H,1)
-        i = jnp.exp(li)[..., None]
-        kf = kt.astype(jnp.float32)
-        S = f[..., None] * S + (i * kf)[..., None] * vt.astype(jnp.float32)[..., None, :]
-        n = f * n + i * kf
-        qf = qt.astype(jnp.float32) * scale
-        y = jnp.einsum("bhk,bhkv->bhv", qf, S)
-        if normalize:
-            den = jnp.maximum(jnp.abs(jnp.einsum("bhk,bhk->bh", qf, n)), 1.0)
-            y = y / den[..., None]
-        return (S, n), y
-
-    S0 = jnp.zeros((b, h, dk, dv), jnp.float32)
-    n0 = jnp.zeros((b, h, dk), jnp.float32)
-    xs = jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0).astype(jnp.float32),
-                      (q, k, v, log_f, log_i))
-    (S, n), ys = jax.lax.scan(step, (S0, n0), xs)
-    return jnp.moveaxis(ys, 0, 1).astype(q.dtype), (S, n)
 
 
 def ranking_scores_ref(lam, z, resid, sizes, cached, omega: float):

@@ -23,8 +23,8 @@ Callers do not use this module directly: ``sweep_grid(..., devices=d)`` /
 lowers to exactly the single-device graph, bypassing this module
 entirely).  Importing this module never touches jax device state — the
 same contract as :mod:`repro.launch.mesh` — so ``XLA_FLAGS``-forced host
-device counts (the run.sh trick used by ``launch/dryrun.py`` and
-``benchmarks/probe_memory.py``) keep working as long as they are set
+device counts (the trick ``benchmarks/probe_memory.py`` and the
+fabric parity tests use) keep working as long as they are set
 before jax initializes.
 """
 from __future__ import annotations

@@ -1,32 +1,13 @@
-"""Production mesh builders.
+"""The sweep fabric's 1-D ``data`` device mesh.
 
-Defined as functions (never module-level constants) so importing this module
-never touches jax device state — the dry-run must set XLA_FLAGS before any
-device query.
+Built by a function (never a module-level constant) so importing this module
+never touches jax device state: ``XLA_FLAGS`` that force a host device count
+must be set before the first device query.
 """
 from __future__ import annotations
 
 import jax
 from jax.sharding import AxisType
-
-
-def _make_mesh(shape, axes):
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """Single pod: 16x16 = 256 chips (data, model).
-    Multi-pod: 2 pods x 256 = 512 chips (pod, data, model)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
-
-
-def make_local_mesh():
-    """1-device mesh with the production axis names — smoke tests / examples
-    run the same sharded code paths without placeholder devices."""
-    return _make_mesh((1, 1), ("data", "model"))
 
 
 def make_data_mesh(n_devices: int | None = None, devices=None):

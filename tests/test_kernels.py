@@ -1,134 +1,11 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+"""Pallas kernels vs pure-jnp oracles, in interpret mode."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.ops import (decode_attention, flash_attention, gla_chunk,
-                               ranking_scores)
-
-
-def _tol(dtype):
-    return dict(atol=2e-2, rtol=2e-2) if dtype == jnp.bfloat16 else \
-        dict(atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("b,sq,sk,h,kv,dh", [
-    (1, 128, 128, 4, 4, 64),     # MHA square
-    (2, 64, 256, 8, 2, 64),      # GQA, kv-longer (cache-style)
-    (1, 256, 256, 6, 3, 128),    # odd head group
-    (2, 100, 100, 4, 2, 64),     # non-block-multiple seq (padding path)
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_matches_ref(b, sq, sk, h, kv, dh, dtype):
-    ks = jax.random.split(jax.random.key(0), 3)
-    q = jax.random.normal(ks[0], (b, sq, h, dh), dtype)
-    k = jax.random.normal(ks[1], (b, sk, kv, dh), dtype)
-    v = jax.random.normal(ks[2], (b, sk, kv, dh), dtype)
-    # q occupies the tail of the k timeline (prefill continuation layout)
-    q_pos = jnp.arange(sk - sq, sk, dtype=jnp.int32)
-    k_pos = jnp.arange(sk, dtype=jnp.int32)
-    got = flash_attention(q, k, v, q_pos, k_pos, block_q=64, block_k=64,
-                          interpret=True)
-    want = ref.flash_attention_ref(q, k, v, q_pos, k_pos)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), **_tol(dtype))
-
-
-@pytest.mark.parametrize("window,softcap,sink", [
-    (0, 0.0, 0), (32, 0.0, 0), (32, 0.0, 8), (0, 30.0, 0)])
-def test_flash_attention_masks_and_softcap(window, softcap, sink):
-    ks = jax.random.split(jax.random.key(1), 3)
-    b, s, h, dh = 1, 192, 4, 64
-    q = jax.random.normal(ks[0], (b, s, h, dh), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, 2, dh), jnp.float32)
-    v = jax.random.normal(ks[2], (b, s, 2, dh), jnp.float32)
-    pos = jnp.arange(s, dtype=jnp.int32)
-    got = flash_attention(q, k, v, pos, pos, window=window, softcap=softcap,
-                          sink=sink, block_q=64, block_k=64,
-                          interpret=True)
-    want = ref.flash_attention_ref(q, k, v, pos, pos, window=window,
-                                   softcap=softcap, sink=sink)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("b,sk,h,kv,dh", [
-    (2, 256, 8, 2, 64), (1, 500, 4, 4, 128), (4, 1024, 8, 1, 64)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_decode_attention_matches_ref(b, sk, h, kv, dh, dtype):
-    ks = jax.random.split(jax.random.key(2), 3)
-    q = jax.random.normal(ks[0], (b, 1, h, dh), dtype)
-    k = jax.random.normal(ks[1], (b, sk, kv, dh), dtype)
-    v = jax.random.normal(ks[2], (b, sk, kv, dh), dtype)
-    q_pos = jnp.array([sk - 1], jnp.int32)
-    k_pos = jnp.arange(sk, dtype=jnp.int32)
-    got = decode_attention(q, k, v, q_pos, k_pos, block_k=128,
-                           interpret=True)
-    want = ref.decode_attention_ref(q, k, v, q_pos, k_pos)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), **_tol(dtype))
-
-
-def test_decode_attention_ring_buffer_masking():
-    """Partially-filled ring cache: empty slots (kpos=-1) must be ignored."""
-    ks = jax.random.split(jax.random.key(3), 3)
-    b, sk, h, kv, dh = 1, 128, 4, 2, 64
-    q = jax.random.normal(ks[0], (b, 1, h, dh), jnp.float32)
-    k = jax.random.normal(ks[1], (b, sk, kv, dh), jnp.float32)
-    v = jax.random.normal(ks[2], (b, sk, kv, dh), jnp.float32)
-    k_pos = jnp.where(jnp.arange(sk) < 70, jnp.arange(sk), -1).astype(jnp.int32)
-    q_pos = jnp.array([69], jnp.int32)
-    got = decode_attention(q, k, v, q_pos, k_pos, block_k=64,
-                           interpret=True)
-    want = ref.decode_attention_ref(q, k, v, q_pos, k_pos)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("b,s,h,dk,dv,chunk", [
-    (1, 128, 2, 16, 32, 32),     # mamba-ish: small state, wide channels
-    (2, 256, 2, 64, 64, 64),     # mLSTM-ish square heads
-    (1, 64, 4, 8, 16, 16),
-])
-@pytest.mark.parametrize("normalize", [True, False])
-def test_gla_chunk_matches_sequential_ref(b, s, h, dk, dv, chunk, normalize):
-    ks = jax.random.split(jax.random.key(4), 5)
-    q = jax.random.normal(ks[0], (b, s, h, dk), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, h, dk), jnp.float32) * 0.3
-    v = jax.random.normal(ks[2], (b, s, h, dv), jnp.float32)
-    log_f = -jax.nn.softplus(-jax.random.normal(ks[3], (b, s, h)) - 1.0)
-    log_i = -jax.nn.softplus(-jax.random.normal(ks[4], (b, s, h)))
-    y, (S, n) = gla_chunk(q, k, v, log_f, log_i, chunk=chunk,
-                          normalize=normalize, interpret=True)
-    y_ref, (S_ref, n_ref) = ref.gla_chunk_ref(q, k, v, log_f, log_i,
-                                              normalize=normalize)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
-                               atol=2e-4, rtol=2e-3)
-    np.testing.assert_allclose(np.asarray(S), np.asarray(S_ref),
-                               atol=2e-4, rtol=2e-3)
-    np.testing.assert_allclose(np.asarray(n), np.asarray(n_ref),
-                               atol=2e-4, rtol=2e-3)
-
-
-def test_gla_chunk_equals_model_chunked_gla():
-    """Kernel == the XLA chunked implementation used by the models."""
-    from repro.models.ssm import chunked_gla
-    ks = jax.random.split(jax.random.key(5), 5)
-    b, s, h, dk, dv = 2, 128, 2, 32, 32
-    q = jax.random.normal(ks[0], (b, s, h, dk), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, h, dk), jnp.float32) * 0.3
-    v = jax.random.normal(ks[2], (b, s, h, dv), jnp.float32)
-    log_f = -jax.nn.softplus(-jax.random.normal(ks[3], (b, s, h)))
-    log_i = -jax.nn.softplus(-jax.random.normal(ks[4], (b, s, h)))
-    y_k, (s_k, n_k) = gla_chunk(q, k, v, log_f, log_i, chunk=32,
-                                interpret=True)
-    y_x, (s_x, n_x) = chunked_gla(q, k, v, log_f, log_i, chunk=32)
-    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_x),
-                               atol=1e-4, rtol=1e-3)
-    np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_x),
-                               atol=1e-4, rtol=1e-3)
+from repro.kernels.ranking_score import ranking_scores
 
 
 @pytest.mark.parametrize("n", [100, 1024, 5000])
@@ -293,38 +170,6 @@ def test_ranking_scores_agrees_with_core_ranking():
                                    PolicyParams(resid="rate"))
     np.testing.assert_allclose(np.asarray(f_k), np.asarray(f_core),
                                rtol=2e-4)
-
-
-def test_slstm_shapes_and_state_continuity():
-    """sLSTM: finite outputs + split-sequence state continuity."""
-    from repro.models.ssm import init_slstm, slstm_apply
-    key = jax.random.key(0)
-    b, s, d, h = 2, 24, 32, 4
-    p = init_slstm(key, d, h)
-    x = jax.random.normal(jax.random.key(1), (b, s, d), jnp.float32) * 0.5
-    y_full, st_full = slstm_apply(p, x, n_heads=h)
-    assert y_full.shape == (b, s, d)
-    assert bool(jnp.all(jnp.isfinite(y_full)))
-    y1, st1 = slstm_apply(p, x[:, :12], n_heads=h)
-    y2, st2 = slstm_apply(p, x[:, 12:], n_heads=h, state=st1)
-    np.testing.assert_allclose(np.asarray(y_full[:, 12:]), np.asarray(y2),
-                               atol=1e-4, rtol=1e-3)
-    for a, bb in zip(st_full, st2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
-                                   atol=1e-4, rtol=1e-3)
-
-
-def test_slstm_gradients_finite():
-    from repro.models.ssm import init_slstm, slstm_apply
-    p = init_slstm(jax.random.key(2), 16, 2)
-    x = jax.random.normal(jax.random.key(3), (1, 10, 16), jnp.float32)
-
-    def loss(p):
-        y, _ = slstm_apply(p, x, n_heads=2)
-        return jnp.sum(y.astype(jnp.float32) ** 2)
-
-    g = jax.grad(loss)(p)
-    assert all(bool(jnp.all(jnp.isfinite(l))) for l in jax.tree.leaves(g))
 
 
 # ---------------------------------------------------------------------------

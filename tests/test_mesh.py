@@ -1,12 +1,11 @@
-"""Mesh builders (repro.launch.mesh): axis names, axis types, and the
-import-side-effect-free contract.
+"""The data mesh builder (repro.launch.mesh): axis name, axis type, device
+order, and the import-side-effect-free contract.
 
-``make_production_mesh`` needs 256+ devices, so its axis wiring is checked
-against a capturing stand-in for ``jax.make_mesh`` rather than by building
-the mesh.  The import-purity contract — importing the launch modules never
-queries jax devices, so ``XLA_FLAGS``-forced host device counts set *after*
-import but *before* first device use still take effect — is a subprocess
-regression test, since an in-process jax is already initialized.
+The axis type is checked against a capturing stand-in for
+``jax.sharding.Mesh``.  The import-purity contract — importing the launch
+modules never queries jax devices, so ``XLA_FLAGS``-forced host device counts
+set *after* import but *before* first device use still take effect — is a
+subprocess regression test, since an in-process jax is already initialized.
 """
 import os
 import subprocess
@@ -22,36 +21,23 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 @pytest.fixture
-def capture_make_mesh(monkeypatch):
+def capture_mesh(monkeypatch):
     calls = []
 
-    def fake(shape, axes, **kw):
-        calls.append((tuple(shape), tuple(axes), dict(kw)))
+    def fake(devices, axes, **kw):
+        calls.append((devices, tuple(axes), dict(kw)))
         return "mesh"
 
-    monkeypatch.setattr(jax, "make_mesh", fake)
+    monkeypatch.setattr(jax.sharding, "Mesh", fake)
     return calls
 
 
-def test_production_mesh_axis_names(capture_make_mesh):
-    mesh_mod.make_production_mesh()
-    mesh_mod.make_production_mesh(multi_pod=True)
-    (s1, a1, _), (s2, a2, _) = capture_make_mesh
-    assert (s1, a1) == ((16, 16), ("data", "model"))
-    assert (s2, a2) == ((2, 16, 16), ("pod", "data", "model"))
-
-
-def test_axis_type_forwarded_new_jax(capture_make_mesh):
-    """Every mesh axis is built as an Auto axis (sharding left to XLA)."""
-    mesh_mod.make_production_mesh(multi_pod=True)
-    _, axes, kw = capture_make_mesh[0]
-    assert kw == {"axis_types": (AxisType.Auto,) * len(axes)}
-
-
-def test_local_mesh_builds_on_one_device():
-    m = mesh_mod.make_local_mesh()
-    assert m.axis_names == ("data", "model")
-    assert dict(m.shape) == {"data": 1, "model": 1}
+def test_axis_type_forwarded_new_jax(capture_mesh):
+    """The data axis is built as an Auto axis (sharding left to XLA)."""
+    mesh_mod.make_data_mesh()
+    _, axes, kw = capture_mesh[0]
+    assert axes == ("data",)
+    assert kw == {"axis_types": (AxisType.Auto,)}
 
 
 def test_data_mesh():
