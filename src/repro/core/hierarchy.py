@@ -48,7 +48,7 @@ from .ranking import POLICIES, PolicyParams
 from .simulator import (SimResult, _behavior_multi, _behavior_static,
                         _commit_due, _commit_one, _serve, _tree_sel,
                         batched_update_mode)
-from .state import SimState, init_state
+from .state import SimState, get_col, init_state, obj_record
 from .trace import Trace
 
 __all__ = ["HierTrace", "HierResult", "make_hier_trace", "simulate_hier",
@@ -236,7 +236,8 @@ def _hier_step(b1, b2, p1, p2, estimate_z, sizes, shard_ids, carry,
     l1 = _commit_due_stacked(b1, p1, estimate_z, l1, sizes, t)
 
     # --- does the request miss at its L1 shard? --------------------------
-    is_l1_miss = ~(l1.obj.cached[s, i] | l1.obj.in_flight[s, i])
+    r = obj_record(jax.vmap(lambda b: get_col(b, i))(l1.block)[s])
+    is_l1_miss = ~(r.cached | r.in_flight)
 
     # --- conditional L2 arrival: resolution time R_L2(t) -----------------
     # the serve's write gate carries the condition (O(1) no-op writes when

@@ -164,25 +164,6 @@ def agg_std_hat(o: ObjStats) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Scalar-at-index estimators (the O(1) serve path, DESIGN.md §10).
-# Element j of the [N]-vector estimators above, as pure scalar gathers —
-# elementwise ops on a gathered element are bit-identical to gathering
-# element j of the vector result, so the serve path can stop materializing
-# N-vectors for one scalar.
-# ---------------------------------------------------------------------------
-def lambda_hat_at(o: ObjStats, p: PolicyParams, j) -> jax.Array:
-    """``lambda_hat(o, p)[j]`` without building the [N] vector."""
-    lam = 1.0 / jnp.maximum(o.gap_mean[j], EPS)
-    return jnp.where(o.count[j] >= 2.0, lam, p.cold_rate)
-
-
-def agg_mean_hat_at(o: ObjStats, j) -> jax.Array:
-    """``agg_mean_hat(o)[j]`` without building the [N] vector."""
-    m = o.agg_sum[j] / jnp.maximum(o.agg_cnt[j], 1.0)
-    return jnp.where(o.agg_cnt[j] > 0.0, m, o.z_est[j])
-
-
-# ---------------------------------------------------------------------------
 # Shared scoring substrate (computed once per commit; DESIGN.md §10).
 # ---------------------------------------------------------------------------
 class Substrate:

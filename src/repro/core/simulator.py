@@ -38,12 +38,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from .distributions import Exponential
-from .ranking import (POLICIES, Policy, PolicyParams, agg_mean_hat_at,
-                      epi_stochastic_vacdh, lambda_hat_at, make_substrate)
+from .ranking import (POLICIES, Policy, PolicyParams, agg_mean_hat,
+                      epi_stochastic_vacdh, lambda_hat, make_substrate)
 from .spans import scope, span
-from .state import (ObjStats, SimState, SlotState, SlotView, init_slot_state,
-                    init_state, kahan_add, lane_add, lane_set, onehot_add,
-                    onehot_set, shift_times, slot_home, slot_probe,
+from .state import (ROW, SimState, SlotState, SlotView, fresh_record,
+                    get_col, init_slot_state, init_state, kahan_add,
+                    lane_set_col, obj_record, obj_rows, onehot_col,
+                    pack_record, set_col, shift_times, slot_home, slot_probe,
                     slot_table_size)
 from .trace import RequestStream, Trace, stream_of_trace
 
@@ -160,13 +161,14 @@ class _Behavior(NamedTuple):
     AdaptSize consumes the coin either way).
 
     ``update`` — state-update lowering, one of :data:`_UPDATE_MODES`
-    (DESIGN.md §11).  'scatter': O(1) point scatters, the unbatched fast
-    path.  'lane': the ``custom_vmap`` lane seam — identical scatters
-    unbatched, ONE diagonal scatter over the stacked ``[L, N]`` state when
-    the graph is vmapped (O(1) per lane; the default for every batched
-    graph).  'onehot': O(N) masked selects, the historical batched
-    lowering, kept as the parity oracle.  All three write bit-identical
-    states, so the choice never shows up in results (tests/test_hotpath.py,
+    (DESIGN.md §11): how a column of the ``[F, N]`` state block is written.
+    'scatter': one dynamic-update-slice, the unbatched fast path.  'lane':
+    the ``custom_vmap`` lane seam — the same write unbatched, ONE diagonal
+    scatter over the stacked ``[L, F, N]`` state when the graph is vmapped
+    (O(F) per lane; the batched lowering for large universes).  'onehot':
+    one masked select over the block, the historical batched lowering,
+    kept as the parity oracle.  All three write bit-identical states, so
+    the choice never shows up in results (tests/test_hotpath.py,
     tests/test_sweep.py).
 
     ``evict_top`` — length of the precomputed victim order consumed by the
@@ -174,12 +176,12 @@ class _Behavior(NamedTuple):
     per-eviction argmin only).  Any value yields bitwise-identical results
     (tests/test_hotpath.py) — it is purely a dispatch-shape knob.
 
-    The write helpers take ``valid`` (python ``True``, constant-folded to
-    the plain write, or a traced bool): an invalid write stores the
-    target's own bits back — an O(1) no-op in the scatter/lane lowerings,
-    a mask term in the one-hot one — which is what lets the streaming
-    engine's padded tail steps run the normal step graph instead of a
-    whole-state select tree (DESIGN.md §11).
+    The writes take ``valid`` (python ``True``, constant-folded to the
+    plain write, or a traced bool): an invalid write stores the column's
+    own bits back — an O(1) no-op in the scatter/lane lowerings, a mask
+    term in the one-hot one — which is what lets the streaming engine's
+    padded tail steps run the normal step graph instead of a whole-state
+    select tree (DESIGN.md §11).
     """
 
     select: object
@@ -192,39 +194,35 @@ class _Behavior(NamedTuple):
     evict_top: int
 
     # --- state writes (see ``update``) -----------------------------------
-    def set_at(self, x, j, jhot, val, valid=True):
+    def pack(self, r):
+        """A record of scalars as the column :meth:`write_col` writes."""
+        return pack_record(r, unbatched=self.update == "scatter")
+
+    def write_col(self, block, j, col, valid=True):
+        """``block[:, j] = col`` where ``valid``."""
         if self.update == "onehot":
-            hot = jhot if valid is True else jhot & valid
-            return onehot_set(x, hot, val)
+            hot = onehot_col(block, j)
+            if valid is not True:
+                hot = hot & valid
+            col = jnp.expand_dims(col, tuple(range(1, block.ndim)))
+            return jnp.where(hot, col, block)
         if valid is not True:
-            val = jnp.where(valid, val, x[j])
-        return lane_set(x, j, val) if self.update == "lane" \
-            else x.at[j].set(val)
+            col = jnp.where(valid, col, get_col(block, j))
+        return lane_set_col(block, j, col) if self.update == "lane" \
+            else set_col(block, j, col)
 
-    def add_at(self, x, j, jhot, val, valid=True):
+    def cond_set_flag(self, block, row, j, cond, val):
+        """``block[row, j] = val`` where ``cond`` (the eviction/admission
+        writes to the ``cached`` row): one column read and one column
+        write, bit-identical to the historical whole-table select.  The
+        one-hot lowering selects over that row alone."""
         if self.update == "onehot":
-            hot = jhot if valid is True else jhot & valid
-            return onehot_add(x, hot, val)
-        if valid is not True:
-            new = jnp.where(valid, x[j] + val, x[j])
-            return lane_set(x, j, new) if self.update == "lane" \
-                else x.at[j].set(new)
-        return lane_add(x, j, val) if self.update == "lane" \
-            else x.at[j].add(val)
-
-    def cond_set_at(self, x, j, cond, val):
-        """x[j] = val where ``cond`` (the eviction/admission writes).
-
-        One-hot keeps the oracle form ``where(cond & hot, val, x)``; the
-        scatter/lane lowerings write ``where(cond, val, x[j])`` at ``j`` —
-        an O(1) gather+scatter, bit-identical to the historical
-        ``where(cond, x.at[j].set(val), x)`` whole-table select."""
-        if self.update == "onehot":
-            hot = jnp.arange(x.shape[0]) == j
-            return jnp.where(cond & hot, val, x)
-        new = jnp.where(cond, val, x[j])
-        return lane_set(x, j, new) if self.update == "lane" \
-            else x.at[j].set(new)
+            hot = onehot_col(block, j)[0] & cond
+            return jax.lax.dynamic_update_index_in_dim(
+                block, jnp.where(hot, val, block[row]), row, 0)
+        col = get_col(block, j)
+        at_row = jnp.arange(col.shape[-1]) == row
+        return self.write_col(block, j, jnp.where(at_row & cond, val, col))
 
 
 def _static_false(flag) -> bool:
@@ -373,14 +371,14 @@ class SimResult(NamedTuple):
         return self.n_hits / jnp.maximum(self.n_requests, 1.0)
 
 
-def _gd_cost_at(b: _Behavior, o, sizes, p: PolicyParams, j):
-    """GreedyDual cost term (MAD-style aggregate-delay costs) for object
-    ``j`` — a scalar gather chain, never an [N] vector (DESIGN.md §10;
-    elementwise ops on gathered elements are bit-identical to indexing the
-    historical full-table result)."""
-    cost = agg_mean_hat_at(o, j)
-    cost = _sel(b.gd_rate, cost * lambda_hat_at(o, p, j), cost)
-    return cost / jnp.maximum(sizes[j], _EPS)
+def _gd_cost(b: _Behavior, r, size, p: PolicyParams):
+    """GreedyDual cost term (MAD-style aggregate-delay costs) of one
+    object's record ``r`` of size ``size`` — scalar arithmetic, never an
+    [N] vector (DESIGN.md §10; elementwise ops on one object's values are
+    bit-identical to indexing the full-table estimators)."""
+    cost = agg_mean_hat(r)
+    cost = _sel(b.gd_rate, cost * lambda_hat(r, p), cost)
+    return cost / jnp.maximum(size, _EPS)
 
 
 def _argmin_id(vals, ids):
@@ -412,33 +410,28 @@ def _commit_one(b: _Behavior, p: PolicyParams, estimate_z: bool,
     entries from the masked table the order was computed over).
     """
     n = sizes.shape[0]
-    o = state.obj
+    block = state.block
+    o = obj_rows(block)
     done_t = jnp.where(o.in_flight, o.complete_t, jnp.inf)
     j = _argmin_id(done_t, ids)
-    jhot = (jnp.arange(n) == j) if b.update == "onehot" else None
-    t_c = o.complete_t[j]
-    realized = t_c - o.issue_t[j]
-    ep = o.episode_delay[j]
+    r = obj_record(get_col(block, j))
+    t_c = r.complete_t
+    realized = t_c - r.issue_t
+    ep = r.episode_delay
 
     # --- finalize the miss episode's statistics -------------------------
-    o = o._replace(
-        agg_sum=b.add_at(o.agg_sum, j, jhot, ep),
-        agg_sq_sum=b.add_at(o.agg_sq_sum, j, jhot, ep * ep),
-        agg_cnt=b.add_at(o.agg_cnt, j, jhot, 1.0),
-        episode_delay=b.set_at(o.episode_delay, j, jhot, 0.0),
-        in_flight=b.set_at(o.in_flight, j, jhot, False),
-        complete_t=b.set_at(o.complete_t, j, jhot, jnp.inf),
-    )
+    r = r._replace(agg_sum=r.agg_sum + ep, agg_sq_sum=r.agg_sq_sum + ep * ep,
+                   agg_cnt=r.agg_cnt + 1.0, episode_delay=0.0,
+                   in_flight=False, complete_t=jnp.inf)
     if estimate_z:
-        znew = 0.7 * o.z_est[j] + 0.3 * realized
-        o = o._replace(z_est=b.set_at(o.z_est, j, jhot, znew))
-    min_complete = jnp.min(jnp.where(o.in_flight, o.complete_t, jnp.inf))
+        r = r._replace(z_est=0.7 * r.z_est + 0.3 * realized)
 
     # --- admission coin (AdaptSize) --------------------------------------
     key = state.key
+    s_j = sizes[j]
     if b.split_key:
         key, sub = jax.random.split(key)
-        p_admit = jnp.exp(-sizes[j] / p.adapt_c)
+        p_admit = jnp.exp(-s_j / p.adapt_c)
         admit_ok = _sel(b.adaptsize, jax.random.uniform(sub) < p_admit,
                         jnp.asarray(True))
     else:
@@ -447,10 +440,11 @@ def _commit_one(b: _Behavior, p: PolicyParams, estimate_z: bool,
     # --- GreedyDual H refresh at the exact completion time ---------------
     gd_clock = state.gd_clock
     if not _static_false(b.greedydual):
-        hj = gd_clock + _gd_cost_at(b, o, sizes, p, j)
-        o = o._replace(gd_h=b.set_at(o.gd_h, j, jhot,
-                                     _sel(b.greedydual, hj, o.gd_h[j])))
-    s_j = sizes[j]
+        hj = gd_clock + _gd_cost(b, r, s_j, p)
+        r = r._replace(gd_h=_sel(b.greedydual, hj, r.gd_h))
+    block = b.write_col(block, j, b.pack(r))
+    o = obj_rows(block)
+    min_complete = jnp.min(jnp.where(o.in_flight, o.complete_t, jnp.inf))
     top = min(b.evict_top, n)
 
     # --- fused rank-and-select, gated on the commit needing space --------
@@ -469,58 +463,58 @@ def _commit_one(b: _Behavior, p: PolicyParams, estimate_z: bool,
     # --- evict-until-fit (only victims ranked strictly below incomer) ----
     # phase 1: walk the precomputed ascending victim order, O(1) each
     def cond1(carry):
-        cached, free, clock, ok, nev, k = carry
+        block, free, clock, ok, nev, k = carry
         return ok & (free < s_j) & (k < top)
 
     def body1(carry):
-        cached, free, clock, ok, nev, k = carry
+        block, free, clock, ok, nev, k = carry
         v = order_idx[k]
         vv = order_vals[k]
         can = vv < cmp
-        cached = b.cond_set_at(cached, v, can, False)
+        block = b.cond_set_flag(block, ROW.cached, v, can, 0.0)
         free = jnp.where(can, free + sizes[v], free)
         nev = jnp.where(can, nev + 1.0, nev)
         clock = _sel(b.greedydual,
                      jnp.where(can, jnp.maximum(clock, vv), clock), clock)
-        return cached, free, clock, can, nev, k + 1
+        return block, free, clock, can, nev, k + 1
 
     if top:
         with scope("evict"):
-            cached, free, gd_clock, fit_ok, n_ev, _ = jax.lax.while_loop(
-                cond1, body1, (o.cached, state.free, gd_clock, admit_ok,
+            block, free, gd_clock, fit_ok, n_ev, _ = jax.lax.while_loop(
+                cond1, body1, (block, state.free, gd_clock, admit_ok,
                                state.n_evictions, jnp.int32(0)))
     else:       # evict_top=0: the legacy graph — phase 2 does all the work
-        cached, free, fit_ok, n_ev = (o.cached, state.free, admit_ok,
-                                      state.n_evictions)
+        free, fit_ok, n_ev = state.free, admit_ok, state.n_evictions
 
     # phase 2: legacy per-eviction argmin — runs only when one admission
     # needs more than ``top`` victims (rare; zero iterations otherwise)
     def cond2(carry):
-        cached, free, clock, ok, nev = carry
+        block, free, clock, ok, nev = carry
         return ok & (free < s_j)
 
     def body2(carry):
-        cached, free, clock, ok, nev = carry
-        vr = jnp.where(cached, ranks, jnp.inf)
-        v = _argmin_id(vr, ids)
-        can = vr[v] < cmp
-        cached = b.cond_set_at(cached, v, can, False)
+        block, free, clock, ok, nev = carry
+        v = _argmin_id(jnp.where(obj_rows(block).cached, ranks, jnp.inf), ids)
+        # the victim's masked rank from its own column, not from the
+        # masked table (which the argmin then need not keep)
+        vv = jnp.where(obj_record(get_col(block, v)).cached, ranks[v], jnp.inf)
+        can = vv < cmp
+        block = b.cond_set_flag(block, ROW.cached, v, can, 0.0)
         free = jnp.where(can, free + sizes[v], free)
         nev = jnp.where(can, nev + 1.0, nev)
         clock = _sel(b.greedydual,
-                     jnp.where(can, jnp.maximum(clock, vr[v]), clock), clock)
-        return cached, free, clock, can, nev
+                     jnp.where(can, jnp.maximum(clock, vv), clock), clock)
+        return block, free, clock, can, nev
 
     with scope("evict"):
-        cached, free, gd_clock, fit_ok, n_ev = jax.lax.while_loop(
-            cond2, body2, (cached, free, gd_clock, fit_ok, n_ev))
+        block, free, gd_clock, fit_ok, n_ev = jax.lax.while_loop(
+            cond2, body2, (block, free, gd_clock, fit_ok, n_ev))
 
     do_admit = admit_ok & fit_ok & (free >= s_j)
-    cached = b.cond_set_at(cached, j, do_admit, True)
+    block = b.cond_set_flag(block, ROW.cached, j, do_admit, 1.0)
     free = jnp.where(do_admit, free - s_j, free)
-    o = o._replace(cached=cached)
 
-    return state._replace(obj=o, free=free, gd_clock=gd_clock,
+    return state._replace(block=block, free=free, gd_clock=gd_clock,
                           min_complete=min_complete, key=key,
                           n_evictions=n_ev)
 
@@ -547,78 +541,69 @@ def _serve(b: _Behavior, p: PolicyParams, state: SimState,
     state's Kahan sum, but callers that feed one tier's resolution time into
     another tier's fetch (the hierarchy, DESIGN.md §8) need it directly.
 
-    This path is O(1) per request in unbatched graphs — scalar gathers and
-    point scatters only; the GreedyDual upkeep (the one historical O(N)
-    full-table cost build) is a scalar gather chain and is folded out of
-    the graph entirely for statically non-GreedyDual policies
-    (DESIGN.md §10).
+    This path is O(1) per request in unbatched graphs — the object's
+    column of the state block is read in one slice, its new values are
+    computed as scalars, and the column is written back in one update; the
+    GreedyDual upkeep (the one historical O(N) full-table cost build) is
+    scalar arithmetic on that record and is folded out of the graph
+    entirely for statically non-GreedyDual policies (DESIGN.md §10).
 
     ``valid`` gates every state write (DESIGN.md §11): python ``True``
     constant-folds to the plain serve; a traced bool makes the serve a
-    bitwise no-op on the state when False — point writes store the
-    target's own bits back (O(1)), scalar accumulators are selected —
+    bitwise no-op on the state when False — the column write stores the
+    column's own bits back (O(1)), scalar accumulators are selected —
     while the returned latency is computed either way (the hierarchy reads
     it off conditional L2 serves).  This replaces the historical
     whole-state select tree for padded streaming steps and the
     hierarchy's owner/L2 masks, whose per-step O(state) cost was the
     measured ~3x padded-tail penalty (EXPERIMENTS.md §Perf iteration 6).
     """
-    o = state.obj
-    ihot = (jnp.arange(sizes.shape[0]) == i) if b.update == "onehot" else None
     gate = (lambda f: f) if valid is True else (lambda f: f & valid)
-    is_hit = o.cached[i]
-    is_delayed = o.in_flight[i]
+    r = obj_record(get_col(state.block, i))
+    is_hit = r.cached
+    is_delayed = r.in_flight
     is_miss = ~(is_hit | is_delayed)
 
-    lat_delayed = jnp.maximum(o.complete_t[i] - t, 0.0)
+    lat_delayed = jnp.maximum(r.complete_t - t, 0.0)
     lat = jnp.where(is_hit, 0.0, jnp.where(is_delayed, lat_delayed, z_realized))
 
     # --- miss: issue fetch ------------------------------------------------
-    comp = jnp.where(is_miss, t + z_realized, o.complete_t[i])
-    o = o._replace(
-        in_flight=b.set_at(o.in_flight, i, ihot, is_miss | o.in_flight[i],
-                           valid),
-        complete_t=b.set_at(o.complete_t, i, ihot, comp, valid),
-        issue_t=b.set_at(o.issue_t, i, ihot,
-                         jnp.where(is_miss, t, o.issue_t[i]), valid),
-        episode_delay=b.set_at(
-            o.episode_delay, i, ihot,
-            jnp.where(is_miss, z_realized,
-                      o.episode_delay[i] + jnp.where(is_delayed, lat, 0.0)),
-            valid),
-    )
+    comp = jnp.where(is_miss, t + z_realized, r.complete_t)
     min_complete = jnp.minimum(state.min_complete,
                                jnp.where(gate(is_miss), comp, jnp.inf))
 
     # --- access statistics (every request) --------------------------------
-    cnt = o.count[i]
-    gap = t - o.last_access[i]
+    cnt = r.count
+    gap = t - r.last_access
     # running mean for the first `window` gaps, then EWMA(1/window):
     a_eff = jnp.maximum(1.0 / p.window, 1.0 / jnp.maximum(cnt, 1.0))
-    gm = jnp.where(cnt <= 0.0, o.gap_mean[i],
+    gm = jnp.where(cnt <= 0.0, r.gap_mean,
                    jnp.where(cnt == 1.0, gap,
-                             o.gap_mean[i] + a_eff * (gap - o.gap_mean[i])))
-    o = o._replace(
-        gap_mean=b.set_at(o.gap_mean, i, ihot, gm, valid),
-        first_access=b.set_at(o.first_access, i, ihot,
-                              jnp.where(cnt == 0.0, t, o.first_access[i]),
-                              valid),
-        last_access=b.set_at(o.last_access, i, ihot, t, valid),
-        count=b.set_at(o.count, i, ihot, cnt + 1.0, valid),
+                             r.gap_mean + a_eff * (gap - r.gap_mean)))
+    r = r._replace(
+        in_flight=is_miss | r.in_flight,
+        complete_t=comp,
+        issue_t=jnp.where(is_miss, t, r.issue_t),
+        episode_delay=jnp.where(
+            is_miss, z_realized,
+            r.episode_delay + jnp.where(is_delayed, lat, 0.0)),
+        gap_mean=gm,
+        first_access=jnp.where(cnt == 0.0, t, r.first_access),
+        last_access=t,
+        count=cnt + 1.0,
     )
     if not _static_false(b.greedydual):
-        hi = state.gd_clock + _gd_cost_at(b, o, sizes, p, i)
-        o = o._replace(gd_h=b.set_at(
-            o.gd_h, i, ihot,
-            _sel(b.greedydual, jnp.where(is_hit, hi, o.gd_h[i]), o.gd_h[i]),
-            valid))
+        hi = state.gd_clock + _gd_cost(b, r, sizes[i], p)
+        r = r._replace(gd_h=_sel(b.greedydual, jnp.where(is_hit, hi, r.gd_h),
+                                 r.gd_h))
+    block = b.write_col(state.block, i, b.pack(r), valid)
 
     lat_sum, lat_comp = kahan_add(state.lat_sum, state.lat_comp, lat)
     if valid is not True:
         lat_sum = jnp.where(valid, lat_sum, state.lat_sum)
         lat_comp = jnp.where(valid, lat_comp, state.lat_comp)
     state = state._replace(
-        obj=o, min_complete=min_complete,
+        block=block, min_complete=min_complete,
         lat_sum=lat_sum, lat_comp=lat_comp,
         n_hits=state.n_hits + gate(is_hit),
         n_delayed=state.n_delayed + gate(is_delayed),
@@ -723,7 +708,7 @@ def _slot_result_of_state(state: SlotState) -> SlotResult:
 # runs unchanged over an [S]-shaped slot axis; a hashed open-addressing
 # table (repro.core.state.SlotView) maps raw object ids onto slots at serve
 # time.  Bitwise parity with dense mode holds by construction whenever the
-# table never fills: per-object arithmetic is scalar gathers at the
+# table never fills: per-object arithmetic runs on the column of the
 # object's slot, and every reduction over the slot axis is either
 # order-independent (min) or id-tiebroken (_argmin_id), so the hash seed
 # and slot layout cannot leak into results (tests/test_slots.py).
@@ -766,38 +751,24 @@ def _slot_lookup_insert(state: SlotState, obj, size, zp, valid):
 
         v, reclaim = jax.lax.cond(has_space, lambda: (slot, jnp.int32(0)),
                                   reclaimed)
-        o = sim.obj
-        was_cached = o.cached[v]
-        was_inflight = o.in_flight[v]
-        o = ObjStats(
-            cached=o.cached.at[v].set(False),
-            in_flight=o.in_flight.at[v].set(False),
-            complete_t=o.complete_t.at[v].set(jnp.inf),
-            issue_t=o.issue_t.at[v].set(0.0),
-            last_access=o.last_access.at[v].set(-jnp.inf),
-            first_access=o.first_access.at[v].set(-jnp.inf),
-            gap_mean=o.gap_mean.at[v].set(0.0),
-            count=o.count.at[v].set(0.0),
-            z_est=o.z_est.at[v].set(zp),
-            agg_sum=o.agg_sum.at[v].set(0.0),
-            agg_sq_sum=o.agg_sq_sum.at[v].set(0.0),
-            agg_cnt=o.agg_cnt.at[v].set(0.0),
-            episode_delay=o.episode_delay.at[v].set(0.0),
-            gd_h=o.gd_h.at[v].set(0.0),
-        )
-        free = jnp.where(was_cached, sim.free + tb.sizes[v], sim.free)
-        nev = jnp.where(was_cached, sim.n_evictions + 1.0, sim.n_evictions)
+        was = obj_record(get_col(sim.block, v))
+        block = set_col(sim.block, v,
+                        pack_record(fresh_record(zp), unbatched=True))
+        free = jnp.where(was.cached, sim.free + tb.sizes[v], sim.free)
+        nev = jnp.where(was.cached, sim.n_evictions + 1.0, sim.n_evictions)
         # reclaiming an in-flight slot invalidates the cached min: recompute
         # (rare; O(S) only inside this branch)
+        o = obj_rows(block)
         min_c = jax.lax.cond(
-            was_inflight,
+            was.in_flight,
             lambda: jnp.min(jnp.where(o.in_flight, o.complete_t, jnp.inf)),
             lambda: sim.min_complete)
         tb = tb._replace(key_tab=tb.key_tab.at[v].set(obj),
                          sizes=tb.sizes.at[v].set(size),
                          n_inserts=tb.n_inserts + 1,
                          n_reclaims=tb.n_reclaims + reclaim)
-        return SlotState(sim=sim._replace(obj=o, free=free, n_evictions=nev,
+        return SlotState(sim=sim._replace(block=block, free=free,
+                                          n_evictions=nev,
                                           min_complete=min_c), tab=tb), v
 
     return jax.lax.cond(fresh, insert, lambda st: (st, slot), state)

@@ -1,22 +1,35 @@
 """Simulator state pytrees for the delayed-hit cache (DESIGN.md §2).
 
-Everything is a struct-of-arrays over the object universe (size N) so the
+The compiled engines carry the per-object state as one float32 block
+``[F, N]`` over the object universe (size N; stored ``[F, N/128, 128]``
+when N fills whole 8x128 tiles, see :func:`row_shape`): one row per
+:class:`ObjStats` field, with the two flags stored as 0.0/1.0.  A request
+reads its object as one column (one dynamic slice) and writes it back as
+one column (one dynamic-update-slice), instead of one gather and one
+scatter per field.  :class:`ObjStats` is the block's *row view* — what the
+scoring code reads — and also the plain per-field container that the
+eager oracle (:mod:`repro.core.refsim`) and the serving engine keep.  The
 whole simulation runs as a single ``lax.scan`` over the request trace with
 ``lax.while_loop`` for the (rare) fetch-commit / eviction events.
 """
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 INF = jnp.inf
 
 
 class ObjStats(NamedTuple):
-    """Per-object online statistics (all shape [N])."""
+    """Per-object online statistics, one value per object.
+
+    As a row view of the compiled engines' ``[F, N]`` block
+    (:func:`obj_rows`) every field is an ``[N]`` row; as a record
+    (:func:`obj_record`) every field is one object's scalar; the eager
+    oracle and the serving engine keep it as ``[N]`` numpy arrays."""
 
     cached: jax.Array        # bool — resident in cache
     in_flight: jax.Array     # bool — fetch outstanding
@@ -34,8 +47,118 @@ class ObjStats(NamedTuple):
     gd_h: jax.Array          # f32 — GreedyDual H value (MAD-style policies)
 
 
+# ---------------------------------------------------------------------------
+# The block: row k holds ObjStats field k, stored in one of two shapes that
+# the block's rank tells apart.  A table of whole 8x128 tiles (N a multiple
+# of 1024) stores each row as an [N/128, 128] slab, object j at
+# (j // 128, j % 128): on the TPU's tiles each field then owns whole tiles,
+# so a scan of one row over a large table reads only that row's bytes,
+# where rows of a flat [F, N] array share their tiles eight to one.  Any
+# other N keeps the flat [F, N] block, whose column sits in two tiles.
+# Packing and unpacking are exact — floats are stored as they are, flags
+# as 0.0/1.0 — so every float32 operation of the engines is the one a
+# per-field layout runs.  The helpers below take one state's block; under
+# ``vmap`` that is each lane's.
+# ---------------------------------------------------------------------------
+N_FIELDS = len(ObjStats._fields)
+ROW = ObjStats(*range(N_FIELDS))         # row of each field: ROW.z_est == 8
+_LANES = 128
+_FLAGS = (ROW.cached, ROW.in_flight)
+_IS_TIME = np.isin(np.arange(N_FIELDS),           # the time-point rows
+                   (ROW.complete_t, ROW.issue_t, ROW.last_access,
+                    ROW.first_access))
+
+
+def row_shape(n: int) -> tuple[int, ...]:
+    """How one row of ``n`` objects is stored: an ``[n/128, 128]`` slab
+    when ``n`` fills whole 8x128 tiles, else flat ``[n]``."""
+    return (n // _LANES, _LANES) if n % (8 * _LANES) == 0 else (n,)
+
+
+def _unpack(field) -> ObjStats:
+    return ObjStats(*(field(k) > 0.0 if k in _FLAGS else field(k)
+                      for k in range(N_FIELDS)))
+
+
+def obj_rows(block: jax.Array) -> ObjStats:
+    """The block as an :class:`ObjStats` of ``[N]`` rows (static slices;
+    the scoring code's view)."""
+    return _unpack(lambda k: block[k].reshape(-1))
+
+
+def pack_rows(o: ObjStats) -> jax.Array:
+    """An :class:`ObjStats` of ``[N]`` rows (scalars broadcast) as the
+    block."""
+    rows = jnp.broadcast_arrays(*(jnp.asarray(v, jnp.float32) for v in o))
+    return jnp.stack(rows).reshape((N_FIELDS,) + row_shape(rows[0].size))
+
+
+def obj_record(col: jax.Array) -> ObjStats:
+    """One object's ``[F]`` column as an :class:`ObjStats` of scalars."""
+    return _unpack(lambda k: col[k])
+
+
+def pack_record(r: ObjStats, unbatched: bool = False) -> jax.Array:
+    """A record of scalars as the ``[F]`` column :func:`set_col` writes.
+
+    One select per field (XLA:CPU fails to compile a stacked column inside
+    the hierarchy's vmapped commit).  In an unbatched graph each field's
+    mask is an iota compare, which the TPU compiler fuses into the
+    selects; constant masks there are hoisted out of the scan as buffers
+    of their own and read from memory at every pack (about 2 µs a request
+    on a v5e).  Batched graphs take constant masks: there the compares
+    stay unfused, a compare op per field on the TPU."""
+    col = jnp.zeros((N_FIELDS,), jnp.float32)
+    masks = (jnp.arange(N_FIELDS) == k for k in range(N_FIELDS)) \
+        if unbatched else np.eye(N_FIELDS, dtype=bool)
+    for at, v in zip(masks, r):
+        col = jnp.where(at, jnp.asarray(v, jnp.float32), col)
+    return col
+
+
+def _col_at(block: jax.Array, j):
+    """Start index and window of object ``j``'s column in the block."""
+    at = (j,) if block.ndim == 2 else (j // _LANES, j % _LANES)
+    return (0,) + at, (N_FIELDS,) + (1,) * len(at)
+
+
+def get_col(block: jax.Array, j) -> jax.Array:
+    """Object ``j``'s column of the block: one dynamic slice."""
+    start, size = _col_at(block, j)
+    return jax.lax.dynamic_slice(block, start, size).reshape(N_FIELDS)
+
+
+def set_col(block: jax.Array, j, col: jax.Array) -> jax.Array:
+    """The block with object ``j``'s column replaced: one
+    dynamic-update-slice."""
+    start, size = _col_at(block, j)
+    return jax.lax.dynamic_update_slice(block, col.reshape(size), start)
+
+
+def onehot_col(block: jax.Array, j) -> jax.Array:
+    """Mask of object ``j``'s position in every row, shaped to broadcast
+    over the block."""
+    row = block.shape[1:]
+    return (jnp.arange(np.prod(row)) == j).reshape((1,) + row)
+
+
+def fresh_record(z_prior) -> ObjStats:
+    """An object's first-touch record: not cached, nothing in flight, no
+    access seen, latency estimate ``z_prior``."""
+    return ObjStats(
+        cached=False, in_flight=False, complete_t=INF, issue_t=0.0,
+        last_access=-INF, first_access=-INF, gap_mean=0.0, count=0.0,
+        z_est=z_prior, agg_sum=0.0, agg_sq_sum=0.0, agg_cnt=0.0,
+        episode_delay=0.0, gd_h=0.0)
+
+
+@jax.jit
+def _fresh_block(z_prior: jax.Array) -> jax.Array:
+    return pack_rows(fresh_record(z_prior))
+
+
 class SimState(NamedTuple):
-    obj: ObjStats
+    block: jax.Array         # f32 [F, N] (or slabs) — row k = ObjStats field k
     free: jax.Array          # f32 scalar — free cache capacity
     gd_clock: jax.Array      # f32 scalar — GreedyDual inflation clock
     min_complete: jax.Array  # f32 scalar — min complete_t over in-flight objects
@@ -47,6 +170,11 @@ class SimState(NamedTuple):
     n_misses: jax.Array
     n_evictions: jax.Array
 
+    @property
+    def obj(self) -> ObjStats:
+        """The block's row view (of one state, not of a stack of them)."""
+        return obj_rows(self.block)
+
 
 def init_state(n_objects: int, capacity: float, key: jax.Array,
                z_prior: jax.Array) -> SimState:
@@ -54,25 +182,15 @@ def init_state(n_objects: int, capacity: float, key: jax.Array,
 
     ``z_prior`` [N] seeds the per-object latency estimate (the known mean of
     the fetch-latency model, as in the paper's setup)."""
-    f = lambda v: jnp.full((n_objects,), v, jnp.float32)
-    b = lambda: jnp.zeros((n_objects,), bool)
-    obj = ObjStats(
-        cached=b(), in_flight=b(),
-        complete_t=f(INF), issue_t=f(0.0),
-        last_access=f(-INF), first_access=f(-INF),
-        gap_mean=f(0.0), count=f(0.0),
-        # jnp.array (copy semantics), NOT asarray: z_est must own its buffer
-        # — the streaming engine donates the state, and an aliased caller
-        # array (e.g. trace.z_mean) would be invalidated with it.
-        z_est=jnp.array(z_prior, jnp.float32),
-        agg_sum=f(0.0), agg_sq_sum=f(0.0), agg_cnt=f(0.0),
-        episode_delay=f(0.0), gd_h=f(0.0),
-    )
+    # one compiled fill: a fresh buffer, never an alias of ``z_prior`` (the
+    # streaming engine donates the state, which would invalidate it)
+    block = _fresh_block(jnp.broadcast_to(
+        jnp.asarray(z_prior, jnp.float32), (n_objects,)))
     # Distinct zero arrays per field: the streaming engine donates the whole
     # state pytree, and XLA rejects donating one buffer behind two leaves.
     zero = lambda: jnp.float32(0.0)
     return SimState(
-        obj=obj,
+        block=block,
         free=jnp.float32(capacity),
         gd_clock=zero(),
         min_complete=jnp.float32(INF),
@@ -95,14 +213,10 @@ def shift_times(state: SimState, delta) -> SimState:
     float, including the ±inf sentinels), which is what keeps the unrebased
     chunked path bit-identical to the single-scan path.
     """
-    o = state.obj
-    o = o._replace(
-        complete_t=o.complete_t - delta,
-        issue_t=o.issue_t - delta,
-        last_access=o.last_access - delta,
-        first_access=o.first_access - delta,
-    )
-    return state._replace(obj=o, min_complete=state.min_complete - delta)
+    block = state.block
+    is_time = _IS_TIME.reshape((N_FIELDS,) + (1,) * (block.ndim - 1))
+    return state._replace(block=jnp.where(is_time, block - delta, block),
+                          min_complete=state.min_complete - delta)
 
 
 # ---------------------------------------------------------------------------
@@ -228,36 +342,26 @@ def kahan_add(total: jax.Array, comp: jax.Array, x: jax.Array):
 
 
 # ---------------------------------------------------------------------------
-# Point-update lowerings (DESIGN.md §11).  Three ways to write "x[j] = v"
-# into per-object state, all bit-identical in results:
+# Column-write lowerings (DESIGN.md §11).  Three ways to write "block[:, j] =
+# col", all bit-identical in results:
 #
-#   scatter  — ``x.at[j].set(v)``: O(1), the unbatched fast path.
-#   one-hot  — masked select over the N-vector: O(N) elementwise, the
+#   scatter  — :func:`set_col`: one dynamic-update-slice, the unbatched
+#              fast path.
+#   one-hot  — a masked select over the block: O(F*N) elementwise, the
 #              historical batched lowering, kept in-tree as the parity
 #              oracle (a batched select leaves untouched positions
 #              bit-identical by construction).
-#   lane     — ``lane_set``/``lane_add`` below: a ``custom_vmap`` seam
-#              whose unbatched form IS the scatter and whose batched form
-#              is ONE scatter over the lane diagonal of the stacked
-#              ``[L, N]`` state (or the Pallas kernel,
-#              :mod:`repro.kernels.lane_scatter`) — O(1) per lane instead
-#              of the one-hot's O(N) per lane.
+#   lane     — :func:`lane_set_col` below: a ``custom_vmap`` seam whose
+#              unbatched form IS the dynamic-update-slice and whose batched
+#              form is ONE scatter over the lane diagonal of the stacked
+#              ``[L, F, N]`` state (or the Pallas kernel,
+#              :mod:`repro.kernels.lane_scatter`) — O(F) per lane instead
+#              of the one-hot's O(F*N) per lane.
 #
-# The one-hot note that used to live here ("batched scatters loop on
-# XLA:CPU") conflated the loop's O(L) trip count with the select's O(L*N)
-# element work; measured at N=3000 the diagonal scatter wins ~3.5x
-# (EXPERIMENTS.md §Perf iteration 6), which is why `lane` is now the
-# default batched lowering and one-hot is the oracle.
+# Measured at N=3000 the diagonal scatter wins ~3.5x over the one-hot
+# (EXPERIMENTS.md §Perf iteration 6), which is why `lane` is the batched
+# lowering for large universes and one-hot the oracle.
 # ---------------------------------------------------------------------------
-def onehot_set(x: jax.Array, hot: jax.Array, val) -> jax.Array:
-    """x with position(s) where ``hot`` is True replaced by ``val``."""
-    return jnp.where(hot, val, x)
-
-
-def onehot_add(x: jax.Array, hot: jax.Array, val) -> jax.Array:
-    """x with ``val`` added at position(s) where ``hot`` is True."""
-    return jnp.where(hot, x + val, x)
-
 
 # Lane-path backend: 'scatter' = the jnp diagonal scatter (CPU fast path
 # and ground truth), 'kernel' = compiled Pallas (TPU), 'kernel_interpret' =
@@ -277,61 +381,50 @@ def set_lane_backend(mode: str) -> None:
     _lane_backend = mode
 
 
-def _lane_dispatch(x, j, v, add: bool):
+def _lane_col_dispatch(x, j, col):
+    """``x[l][:, j[l]] = col[l]`` over the stacked ``[L, F, ...]`` block:
+    the stack seen as ``L*F`` rows, row ``(l, k)`` written at ``j[l]`` by
+    one lane scatter."""
+    lanes = x.shape[0]
+    rows, idx, val = (x.reshape(lanes * N_FIELDS, -1),
+                      jnp.repeat(j, N_FIELDS), col.reshape(-1))
     if _lane_backend == "scatter":
-        from repro.kernels.ref import (lane_scatter_add_ref,
-                                       lane_scatter_set_ref)
-        fn = lane_scatter_add_ref if add else lane_scatter_set_ref
-        return fn(x, j, v)
-    from repro.kernels.lane_scatter import lane_scatter_add, lane_scatter_set
-    fn = lane_scatter_add if add else lane_scatter_set
-    return fn(x, j, v, interpret=(_lane_backend == "kernel_interpret"))
+        from repro.kernels.ref import lane_scatter_set_ref
+        out = lane_scatter_set_ref(rows, idx, val)
+    else:
+        from repro.kernels.lane_scatter import lane_scatter_set
+        out = lane_scatter_set(rows, idx, val,
+                               interpret=(_lane_backend == "kernel_interpret"))
+    return out.reshape(x.shape)
 
 
-def _lane_rule(axis_size, in_batched, x, j, val, *, add: bool):
-    """The batched lowering: one diagonal scatter over the ``[L, N]`` stack.
+def _lane_col_rule(axis_size, in_batched, x, j, col):
+    """The batched lowering: one diagonal scatter over the stacked
+    ``[L, F, ...]`` block.
 
-    Handles every batching combination the simulator produces: ``x`` is
-    (virtually) always batched; ``j`` is batched under lane vmaps whose
-    index is lane-dependent (the sweep engine's commit argmin) and
+    ``x`` is (virtually) always batched; ``j`` is batched under lane vmaps
+    whose index is lane-dependent (the sweep engine's commit argmin) and
     unbatched when every lane writes the same column (the hierarchy's
-    broadcast request id — lowered as a column update, no index vector at
-    all); ``val`` follows the data.  Nested vmaps (traces over lanes,
-    grids over shards) batch the emitted scatter with XLA's stock rules —
-    still one scatter op, never a select tree.
+    broadcast request id — one dynamic-update-slice of that column, no
+    index vector at all); ``col`` follows the data.  Nested vmaps (traces
+    over lanes, grids over shards) batch the emitted scatter with XLA's
+    stock rules — still one scatter op, never a select tree.
     """
-    xb, jb, vb = in_batched
+    xb, jb, cb = in_batched
     if not xb:
         x = jnp.broadcast_to(x, (axis_size,) + jnp.shape(x))
-    val = jnp.asarray(val, x.dtype)
-    if not vb:
-        val = jnp.broadcast_to(val, (axis_size,))
+    col = jnp.asarray(col, x.dtype)
+    if not cb:
+        col = jnp.broadcast_to(col, (axis_size,) + jnp.shape(col))
     if jb:
-        out = _lane_dispatch(x, j, val, add)
-    elif add:
-        col = x[:, j]
-        new = (col | val) if x.dtype == jnp.bool_ else col + val
-        out = x.at[:, j].set(new)
-    else:
-        out = x.at[:, j].set(val)
-    return out, True
+        return _lane_col_dispatch(x, j, col), True
+    return jax.vmap(lambda b, c: set_col(b, j, c))(x, col), True
 
 
 @jax.custom_batching.custom_vmap
-def lane_set(x: jax.Array, j, val) -> jax.Array:
-    """``x.at[j].set(val)`` whose vmapped form is a lane scatter."""
-    return x.at[j].set(jnp.asarray(val, x.dtype))
+def lane_set_col(x: jax.Array, j, col) -> jax.Array:
+    """:func:`set_col` whose vmapped form is a lane scatter."""
+    return set_col(x, j, jnp.asarray(col, x.dtype))
 
 
-@jax.custom_batching.custom_vmap
-def lane_add(x: jax.Array, j, val) -> jax.Array:
-    """``x[j] += val`` whose vmapped form is a lane scatter-add (the sum is
-    formed on the gathered element — identical arithmetic to the one-hot
-    lowering's ``where(hot, x + val, x)`` at the addressed position)."""
-    if x.dtype == jnp.bool_:
-        return x.at[j].set(x[j] | jnp.asarray(val, bool))
-    return x.at[j].set(x[j] + jnp.asarray(val, x.dtype))
-
-
-lane_set.def_vmap(functools.partial(_lane_rule, add=False))
-lane_add.def_vmap(functools.partial(_lane_rule, add=True))
+lane_set_col.def_vmap(_lane_col_rule)

@@ -503,7 +503,7 @@ def sweep_grid(traces, capacities, policies,
         lane_policy = (tuple(lanes.tolist()) if commit_mode == "compact"
                        else None)
         if update is None:
-            # point scatters for an unbatched single lane; once lanes
+            # plain column writes for an unbatched single lane; once lanes
             # batch, the N-dependent batched default (DESIGN.md §11)
             update = batched_update_mode(trace_list[0].n_objects) \
                 if (not single or cflat.shape[0] > 1) else "scatter"

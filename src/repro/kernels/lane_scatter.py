@@ -1,9 +1,11 @@
 """Batched lane-scatter kernel (Pallas, TPU target): point updates with
 lane-varying indices over ``[L, N]`` state.
 
-The simulator's per-object state lives as struct-of-arrays ``[N]``; under
-the sweep engine's lane vmap (policies x params x capacities x seeds) every
-point update carries a *different* index per lane.  Historically that case
+The simulator's per-object state lives as one ``[F, N]`` block, written a
+column at a time; under the sweep engine's lane vmap (policies x params x
+capacities x seeds) every column write carries a *different* index per
+lane, and the simulator hands this kernel the stacked block as ``L*F``
+rows (:func:`repro.core.state.lane_set_col`).  Historically that case
 was lowered as a one-hot masked select — O(N) elementwise work per lane per
 update, the measured N=3000 unified-roster loss (EXPERIMENTS.md §Perf
 iteration 5) — because XLA:CPU executes a batched scatter as a per-lane

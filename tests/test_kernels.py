@@ -224,25 +224,39 @@ def test_lane_scatter_bitwise_matches_onehot(lanes, dtype, add):
 
 
 def test_lane_seam_unbatched_and_batched_forms_agree():
-    """state.lane_set/lane_add: the custom_vmap unbatched form (a point
-    scatter) and the vmapped form (the diagonal scatter) must write the
-    same bits — including a shared scalar index (the hierarchy's broadcast
-    request id), which lowers as a column update."""
-    from repro.core.state import lane_add, lane_set
-    x, idx, val, n = _lane_case(7, jnp.float32, seed=3)
-    want_set = _onehot_oracle(x, idx, val, n, add=False)
-    want_add = _onehot_oracle(x, idx, val, n, add=True)
-    got_set = jax.vmap(lane_set)(x, idx, val)
-    got_add = jax.vmap(lane_add)(x, idx, val)
-    np.testing.assert_array_equal(np.asarray(got_set), np.asarray(want_set))
-    np.testing.assert_array_equal(np.asarray(got_add), np.asarray(want_add))
-    # unbatched == row-wise python loop
-    for l in range(7):
-        np.testing.assert_array_equal(
-            np.asarray(lane_set(x[l], idx[l], val[l])),
-            np.asarray(want_set[l]))
-    # shared scalar index under vmap (in_batched=False for j)
-    j = jnp.int32(11)
-    got = jax.vmap(lambda r, v: lane_set(r, j, v))(x, val)
-    want = jax.vmap(lambda r, v: jnp.where(jnp.arange(n) == j, v, r))(x, val)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    """state.lane_set_col: the custom_vmap unbatched form (one column
+    dynamic-update-slice of the block) and the vmapped form (the diagonal
+    scatter over the stacked block, jnp and interpreted kernel backends)
+    must write the same bits — on both shapes of a row (flat [N], and
+    [N/128, 128] slabs) and with a shared scalar index (the
+    hierarchy's broadcast request id), which lowers as a column update."""
+    from repro.core.state import lane_set_col, set_lane_backend
+    rng = np.random.default_rng(3)
+    lanes, f = 7, 14
+    for row in ((53,), (16, 128)):
+        n = int(np.prod(row))
+        x = jnp.asarray(rng.standard_normal((lanes, f) + row), jnp.float32)
+        col = jnp.asarray(rng.standard_normal((lanes, f)), jnp.float32)
+        idx = jnp.asarray(rng.integers(0, n, lanes), jnp.int32)
+        idx = idx.at[2].set(idx[1])     # two lanes on one column
+        oracle = jax.vmap(lambda b, j, v: jnp.where(
+            (jnp.arange(n) == j).reshape(row),
+            v.reshape((f,) + (1,) * len(row)), b))
+        want = np.asarray(oracle(x, idx, col))
+        for backend in ("scatter", "kernel_interpret"):
+            set_lane_backend(backend)
+            try:
+                got = jax.vmap(lane_set_col)(x, idx, col)
+            finally:
+                set_lane_backend("scatter")
+            np.testing.assert_array_equal(np.asarray(got), want,
+                                          err_msg=f"{backend} {row}")
+        # unbatched == row-wise python loop
+        for l in range(lanes):
+            np.testing.assert_array_equal(
+                np.asarray(lane_set_col(x[l], idx[l], col[l])), want[l])
+        # shared scalar index under vmap (in_batched=False for j)
+        j = jnp.int32(11)
+        got = jax.vmap(lambda b, v: lane_set_col(b, j, v))(x, col)
+        want = oracle(x, jnp.full((lanes,), 11, jnp.int32), col)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

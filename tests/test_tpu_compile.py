@@ -60,6 +60,21 @@ def _compile(fn, *args):
     return compiled
 
 
+def _block_copies_in_loops(text: str, block: str) -> list[str]:
+    """Copies of the whole per-object state block (HLO shape ``block``)
+    outside the entry computation, i.e. inside the scan and commit loops:
+    a read of the block still live after a column write makes the
+    compiler copy all of it, once a request."""
+    copies, entry = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            entry = line.startswith("ENTRY")
+        elif (not entry and re.search(r" copy(-start)?\(", line)
+              and block + "{" in line):
+            copies.append(line.strip()[:160])
+    return copies
+
+
 @pytest.mark.parametrize("n", [4096, 200_000])
 def test_ranking_victim_order_compiles(one_chip, n):
     streams = [_spec(one_chip, (n,), jnp.float32)] * 4
@@ -110,6 +125,29 @@ def test_stream_chunk_step_with_kernel_compiles(one_chip):
         state, f32(chunk), _spec(one_chip, (chunk,), jnp.int32), f32(chunk),
         f32(), f32(n), params)
     assert compiled.memory_analysis() is not None
+    assert not _block_copies_in_loops(compiled.as_text(), "f32[14,32,128]")
+
+
+@pytest.mark.parametrize("policy", ["stoch_vacdh", "lru"])
+def test_paper_scale_chunk_step_keeps_one_state_block(one_chip, policy):
+    """The Fig. 2 replay's chunk step (100 objects, 25000 requests, a flat
+    [14, 100] block): every column write updates the block in place."""
+    n, chunk = 100, 25_000
+    spec = lambda s: _spec(one_chip, s.shape, s.dtype)
+    state = jax.tree.map(spec, jax.eval_shape(
+        lambda: init_state(n, jnp.float32(500.0), jax.random.key(0),
+                           jnp.zeros((n,), jnp.float32))))
+    params = jax.tree.map(lambda x: _spec(one_chip, jnp.shape(x),
+                                          jnp.result_type(x)),
+                          PolicyParams(omega=1.0))
+    f32 = lambda *s: _spec(one_chip, s, jnp.float32)
+    mode = "kernel" if policy == "stoch_vacdh" else "rank"
+    compiled = jax.jit(
+        lambda st, t, o, z, d, sz, p: _chunk_step_jit(
+            st, t, o, z, None, d, sz, p, policy, True, mode)).lower(
+        state, f32(chunk), _spec(one_chip, (chunk,), jnp.int32), f32(chunk),
+        f32(), f32(n), params).compile()
+    assert not _block_copies_in_loops(compiled.as_text(), "f32[14,100]")
 
 
 def test_slot_chunk_step_with_kernel_compiles(one_chip):
@@ -131,3 +169,4 @@ def test_slot_chunk_step_with_kernel_compiles(one_chip):
         state, f32(chunk), _spec(one_chip, (chunk,), jnp.int32), f32(chunk),
         f32(), f32(universe), f32(universe), params)
     assert "ranking_scores" in compiled.as_text()
+    assert not _block_copies_in_loops(compiled.as_text(), "f32[14,1024,128]")
